@@ -28,15 +28,67 @@ from repro.core.protocol import BlockSchedule
 
 
 def ridge_loss_full(w, X, y, lam):
-    """L(w) = (1/N) sum (w^T x - y)^2 + (lam/N)||w||^2   (paper's ell summed)."""
-    r = X @ w - y
+    """L(w) = (1/N) sum (w^T x - y)^2 + (lam/N)||w||^2   (paper's ell summed).
+
+    The matvec runs at HIGHEST precision: the TPU's default f32 matmul
+    rounds its operands to bfloat16, which would change the objective."""
+    r = jnp.matmul(X, w, precision=jax.lax.Precision.HIGHEST) - y
     n = X.shape[0]
     return jnp.mean(r ** 2) + lam / n * jnp.sum(w ** 2)
 
 
-def ridge_grad_sample(w, x, yv, lam, n):
+def ridge_losses(W, X, y, lam):
+    """:func:`ridge_loss_full` of every weight vector in ``W`` (..., d).
+
+    The squared residuals are summed one sample at a time, elementwise
+    over the batch: a vmapped reduction adds in an order that changes with
+    the batch shape (on the CPU), which would let a Monte-Carlo lane's
+    loss depend on how many lanes were solved beside it."""
+    n = X.shape[0]
+
+    def add_sample(total, xy):
+        x, yv = xy
+        r = ridge_dot(W, x) - yv
+        return total + r * r, None
+
+    sq, _ = jax.lax.scan(add_sample, jnp.zeros(W.shape[:-1], W.dtype),
+                         (X, y))
+    return sq / n + lam / n * ridge_dot(W, W)
+
+
+def ridge_dot(a, b, axis: int = -1, keepdims: bool = False,
+              ordered: bool = True):
+    """``sum_k a_k b_k`` over ``axis``: the ``w^T x`` of every ridge SGD
+    step.
+
+    ``ordered`` adds the products left to right, an order XLA's dot and a
+    Mosaic reduction would each choose differently; a compiled Pallas
+    kernel and the ``lax.scan`` engines agree bitwise on it.  Otherwise it
+    is one ``einsum``: XLA:CPU contracts a chain of adds into FMAs
+    differently in each fusion, but evaluates the ``einsum`` alike in
+    every engine.  A Monte-Carlo solve picks one form for all its
+    engines (:func:`repro.fleet.objective_kernels._mc_solve_for`)."""
+    if not ordered:
+        axis = axis % a.ndim
+        sub = "abcdefgh"[:a.ndim]
+        out = jnp.einsum(f"{sub},{sub}->{sub.replace(sub[axis], '')}", a, b,
+                         precision=jax.lax.Precision.HIGHEST)
+        return jnp.expand_dims(out, axis) if keepdims else out
+    prods = a * b
+
+    def term(k):
+        return jax.lax.index_in_dim(prods, k, axis, keepdims=keepdims)
+
+    acc = term(0)
+    for k in range(1, prods.shape[axis]):
+        acc = acc + term(k)
+    return acc
+
+
+def ridge_grad_sample(w, x, yv, lam, n, ordered: bool = True):
     """grad of ell(w, (x,y)) = (w^T x - y)^2 + (lam/N)||w||^2."""
-    return 2.0 * (jnp.dot(w, x) - yv) * x + 2.0 * lam / n * w
+    return (2.0 * (ridge_dot(w, x, ordered=ordered) - yv) * x
+            + 2.0 * lam / n * w)
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,6 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core.bounds import BoundConstants, corollary1_bound
 from repro.core.objectives import BoundObjective
@@ -225,7 +224,7 @@ class RoundPlanner:
             "valid": valid,
         }
         fn = round_solve()
-        with enable_x64():
+        with jax.enable_x64(True):
             if self.shard:
                 arrays = _maybe_shard(arrays, S)
             t0 = time.perf_counter()

@@ -18,10 +18,10 @@ sort-and-prefix-scan:
      rate-major argmin — the same tie-breaking contract as
      ``_reduce_joint_argmin``.
   2. **Participation scan** — devices sort ascending by best-feasible
-     bound (stable: ties keep population order), a prefix cumsum gives
-     the aggregated bound ``F(K)`` for EVERY participant count ``K`` in
-     one pass, and a prefix cummax gives each prefix's straggler-bounded
-     round time.  ``argmin F(K)`` (first minimum, i.e. the smallest
+     bound (stable: ties keep population order), and one sequential
+     scan gives the prefix sum — the aggregated bound ``F(K)`` for EVERY
+     participant count ``K`` — and the prefix max, each prefix's
+     straggler-bounded round time.  ``argmin F(K)`` (first minimum, i.e. the smallest
      optimal ``K``) picks the round.
 
 The aggregated objective is
@@ -92,11 +92,14 @@ def _build_round_solve(branches):
 
         # per-device best feasible point, rate-major tie-breaking (the
         # _reduce_joint_argmin contract: first grid point within a rate,
-        # then first rate)
-        gi_per_rate = jnp.argmin(masked, axis=2)               # (S, R)
-        ri = jnp.argmin(jnp.min(masked, axis=2), axis=1)       # (S,)
+        # then first rate) — which is the first minimum of the flattened
+        # (R, G) block, as the numpy reference takes it.  One flat argmin
+        # also keeps the TPU compile short: every further consumer of the
+        # float64 grid makes XLA duplicate its emulated arithmetic.
+        G = grid.shape[1]
+        flat = jnp.argmin(masked.reshape(S, -1), axis=1)       # (S,)
+        ri, gi = flat // G, flat % G
         s = jnp.arange(S)
-        gi = gi_per_rate[s, ri]
         best = masked[s, ri, gi]                               # +inf if none
         best_t = completion[s, ri, gi]
 
@@ -107,13 +110,27 @@ def _build_round_solve(branches):
         b_sorted = sort_key[order]
         t_sorted = jnp.where(eligible, best_t, -jnp.inf)[order]
 
+        # one sequential pass for both prefix scans: it adds left to
+        # right like np.cumsum (so the sums stay bitwise), and the TPU
+        # compiler takes seconds for it where jnp.cumsum / lax.cummax in
+        # float64 take minutes
+        def prefix(carry, bt):
+            total, latest = carry
+            total = total + bt[0]
+            latest = jnp.maximum(latest, bt[1])
+            return (total, latest), (total, latest)
+
+        _, (b_cum, t_cmax) = jax.lax.scan(
+            prefix, (jnp.zeros((), b_sorted.dtype),
+                     jnp.full((), -jnp.inf, t_sorted.dtype)),
+            (b_sorted, t_sorted))
         K = jnp.arange(1, S + 1, dtype=jnp.float64)
-        curve = jnp.cumsum(b_sorted) / K - sigma * (1.0 - 1.0 / K)
+        curve = b_cum / K - sigma * (1.0 - 1.0 / K)
         n_eligible = jnp.sum(eligible)
         curve = jnp.where(jnp.arange(1, S + 1) <= n_eligible,
                           curve, jnp.inf)
         k_best = jnp.argmin(curve) + 1         # ties -> smallest K
-        round_time = jax.lax.cummax(t_sorted)[k_best - 1]
+        round_time = t_cmax[k_best - 1]
 
         return {
             "order": order, "k_best": k_best,

@@ -37,7 +37,7 @@ def corollary1_bound_jax(n_c, *, N, T, n_o, tau_p, sigma, e0, contraction):
     scalars from :class:`~repro.core.bounds.BoundConstants`
     (``variance_floor``, ``init_gap``, ``contraction``), passed as plain
     arguments so a jitted caller never retraces on new constants.  Call
-    under ``jax.experimental.enable_x64()`` for float64 agreement with
+    under ``jax.enable_x64(True)`` for float64 agreement with
     the reference.
     """
     n_c = jnp.asarray(n_c)

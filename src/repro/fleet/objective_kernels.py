@@ -35,7 +35,7 @@ Built-in kernels:
     CRN scan (``objective.crn=True``: slab-precomputed index/mask tables
     + shared per-slot uniforms + affine-fused update), and the pallas
     slab kernel (``mc_impl="pallas"``,
-    :func:`repro.kernels.mc_ridge.mc_ridge_slab`, interpreted off-TPU)
+    :func:`repro.kernels.mc_ridge.mc_ridge_slab`, interpreted on the CPU)
     which consumes the same tables bitwise.
 
 Registering a kernel for a custom grid objective needs only its value
@@ -60,12 +60,11 @@ from typing import Callable, Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.links import P_ERR_MAX, GilbertElliottLink
 from repro.core.objectives import objective_spec
-from repro.core.pipeline import ridge_grad_sample, ridge_loss_full
+from repro.core.pipeline import ridge_dot, ridge_grad_sample, ridge_losses
 from repro.fleet.bounds_jax import corollary1_bound_jax
 from repro.fleet.link_kernels import kernel_table, kernel_table_version
 from repro.fleet.tracing import record_trace
@@ -324,7 +323,7 @@ def grid_objective_builder(value_fn, exact_arq: bool = False) -> Callable:
             stride = arrays.pop("refine_stride", None)
             width = arrays.pop("refine_width", None)
             S = arrays["N"].shape[0]
-            with enable_x64():
+            with jax.enable_x64(True):
                 if shard:
                     arrays = _maybe_shard(arrays, S)
                 # device/host attribution: the fence makes the jitted
@@ -395,6 +394,11 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
     seed0 = int(objective.seed)
     crn = bool(getattr(objective, "crn", False))
     seed_stream = str(getattr(objective, "seed_stream", "legacy"))
+    # one lane-dot form for every engine of the solve, so they agree
+    # bitwise: the einsum under the interpreter (XLA:CPU fuses an ordered
+    # chain differently in each engine), else the ordered sum, the only
+    # form Mosaic lowers
+    ordered = not interpret
 
     def run_key(r):
         # per-run key derivation, mirroring repro.core.pipeline.mc_run_key
@@ -489,14 +493,13 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
 
         def scan_slab(W, Xs, ys, ix, m):
             # the CRN scan engine's inner loop: affine-fused update, no
-            # RNG, no f64 — einsum keeps the lane dot bitwise-identical
-            # to the exact engine's vmapped jnp.dot (and to the pallas
-            # kernel's interpret-mode dot)
+            # RNG, no f64 — ridge_dot keeps the lane dot bitwise-equal
+            # to the exact engine's and to the pallas kernel's
             def inner(W, row):
                 ixr, mr = row
                 xr = Xs[ixr]
                 yr = ys[ixr]
-                dot = jnp.einsum("ld,ld->l", W, xr)
+                dot = ridge_dot(W, xr, ordered=ordered)
                 c1 = 1.0 - mr * c_reg
                 c2 = mr * c_2a * (dot - yr)
                 return W * c1[:, None] + xr * c2[:, None], None
@@ -505,8 +508,18 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
 
         def pallas_slab(W, Xs, ys, ix, m):
             from repro.kernels.mc_ridge import mc_ridge_slab
-            return mc_ridge_slab(W, Xs, ys, ix, m, alpha=alpha, lam=lam,
-                                 fused=crn, interpret=interpret)
+            slab_fn = partial(mc_ridge_slab, alpha=alpha, lam=lam,
+                              fused=crn, interpret=interpret,
+                              ordered=ordered)
+            if shard_lanes:
+                # XLA cannot partition a Mosaic kernel: each device runs
+                # it on its own lanes (lanes are independent)
+                slab_fn = jax.shard_map(
+                    slab_fn, mesh=mesh,
+                    in_specs=(P("fleet"), P(), P(), P(None, "fleet"),
+                              P(None, "fleet")),
+                    out_specs=P("fleet"), check_vma=False)
+            return slab_fn(W, Xs, ys, ix, m)
 
         def per_run_exact_scan(r):
             # the reference engine: per-slot split + randint INSIDE the
@@ -529,16 +542,16 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
                     lambda b: jax.random.randint(sub, (), 0, b,
                                                  dtype=jnp.int32)
                 )(jnp.maximum(a, 1))
-                grads = jax.vmap(ridge_grad_sample,
-                                 (0, 0, 0, None, None))(W, Xs[idx], ys[idx],
-                                                        lam, n)
+                grads = jax.vmap(
+                    partial(ridge_grad_sample, ordered=ordered),
+                    (0, 0, 0, None, None))(W, Xs[idx], ys[idx], lam, n)
                 W_new = W - alpha * grads
                 W = jnp.where((a > 0)[:, None], W_new, W)
                 return (W, k), None
 
             (W_fin, _), _ = jax.lax.scan(step, (W0, ks),
                                          jnp.arange(max_updates))
-            return jax.vmap(lambda w: ridge_loss_full(w, X, y, lam))(W_fin)
+            return W_fin
 
         def per_run_slabbed(r):
             # table-driven engines: outer scan over slabs; each slab's
@@ -572,7 +585,7 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
 
                 (W_fin, _), _ = jax.lax.scan(outer, (W0, ks),
                                              jnp.arange(nslab))
-            return jax.vmap(lambda w: ridge_loss_full(w, X, y, lam))(W_fin)
+            return W_fin
 
         def crn_scan_all_runs():
             # CRN scan engine, all runs in ONE pass over slabs: the f64
@@ -610,17 +623,18 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
             W_fin, _ = jax.lax.scan(outer, W0_a,
                                     (jnp.arange(nslab),
                                      jnp.moveaxis(u_a, 1, 0)))
-            return jax.vmap(jax.vmap(
-                lambda w: ridge_loss_full(w, X, y, lam)))(W_fin)
+            return W_fin
 
         if mc_impl == "pallas":
             # python loop over runs: vmapping a pallas_call would batch
             # the kernel grid; runs are few, so unrolled calls are fine
-            losses = jnp.stack([per_run_slabbed(r) for r in range(runs)])
+            W_fin = jnp.stack([per_run_slabbed(r) for r in range(runs)])
         elif crn:
-            losses = crn_scan_all_runs()
+            W_fin = crn_scan_all_runs()
         else:
-            losses = jax.vmap(per_run_exact_scan)(jnp.arange(runs))
+            W_fin = jax.vmap(per_run_exact_scan)(jnp.arange(runs))
+        # one (runs, L) loss evaluation shared by every engine
+        losses = ridge_losses(W_fin, X, y, lam)
         vals = jnp.mean(losses, axis=0).astype(T.dtype).reshape(S, R, G)
 
         return _reduce_joint_argmin(vals, n_o_eff, p, N, T, rates,
@@ -652,9 +666,10 @@ def montecarlo_builder(objective) -> Callable:
         mc_impl = arrays.pop("mc_impl", "scan")
         mc_seeds = arrays.pop("mc_seeds", None)
         mc_updates = arrays.pop("mc_updates", None)
-        # the pallas engine runs interpreted off-TPU (CPU CI included)
+        # the pallas engine runs interpreted on the CPU (the test suite)
+        # and compiled everywhere else
         fn = _mc_solve_for(objective, kernel_table_version(),
-                           jax.default_backend() != "tpu")
+                           jax.default_backend() == "cpu")
         # the objective's min_updates floor pins the padded scan length
         # for serving: every batch below the floor shares ONE shape
         # (padded slots no-op, so plans are unaffected)
@@ -673,7 +688,7 @@ def montecarlo_builder(objective) -> Callable:
         lanes = S * arrays["rates"].shape[1] * arrays["grid"].shape[-1]
         shard = bool(shard) and n_dev > 1 and S % n_dev == 0 \
             and lanes % n_dev == 0
-        with enable_x64():
+        with jax.enable_x64(True):
             if shard:
                 arrays = _maybe_shard(arrays, S)
             t0 = time.perf_counter()

@@ -18,8 +18,8 @@ single compilation per objective plans a fleet mixing every registered
 channel family; jitted solves are cached per kernel-table version, so
 registering a new model after import just triggers one retrace.
 
-The whole computation runs under ``jax.experimental.enable_x64()`` to match
-the numpy reference bit-for-bit where the backend's libm allows, and the
+The whole computation runs under ``jax.enable_x64(True)`` to match the
+numpy reference bit-for-bit where the backend's libm allows, and the
 grid objectives are sharded across local devices via
 ``jax.sharding.NamedSharding`` over the scenario axis whenever more than
 one device is visible and ``S`` divides evenly.
@@ -292,11 +292,8 @@ class FleetPlanner:
                 raise ValueError(
                     f"grid has leading dim {grid.shape[0]}, want {S}")
 
-        arrays = self._solve_arrays(batch, grid)
         solve = fleet_solve(objective)
-        impl = self._resolve_mc_impl()
-        if impl != "scan" and getattr(solve, "supports_mc_impl", False):
-            arrays["mc_impl"] = impl  # popped host-side by the MC builder
+        arrays = self._kernel_arrays(solve, batch, grid)
         out = None
         if mode == "refine":
             out, fine_grid = self._refine_solve(solve, arrays, consts,
@@ -321,6 +318,16 @@ class FleetPlanner:
             n_c_per_device=np.maximum(1, out["n_c"] // D),
             grid=fine_grid, bound_grid=out["bound_grid"],
             objective=objective.objective_id)
+
+    def _kernel_arrays(self, solve, batch, grid):
+        """The solve's input arrays, with the Monte-Carlo engine for a
+        kernel that takes one: warmup and serving must compile the same
+        engine."""
+        arrays = self._solve_arrays(batch, grid)
+        impl = self._resolve_mc_impl()
+        if impl != "scan" and getattr(solve, "supports_mc_impl", False):
+            arrays["mc_impl"] = impl  # popped host-side by the MC builder
+        return arrays
 
     def _refine_solve(self, solve, arrays, consts, batch, objective, grid):
         """The two-pass coarse -> fine solve; ``(None, None)`` signals a
@@ -552,8 +559,8 @@ class FleetPlanner:
         batch = ScenarioBatch.from_scenarios(
             _pad_batch(list(scenarios), pad_to))
         grid = self._default_grid(batch, objective)
-        arrays = self._solve_arrays(batch, grid)
         solve = fleet_solve(objective)
+        arrays = self._kernel_arrays(solve, batch, grid)
         with trace_delta() as traces:
             self._warm_sweep(solve, arrays, consts, batch, grid, mode,
                              objective)

@@ -28,10 +28,8 @@ engine; ``fused=False`` replicates
 :func:`repro.core.pipeline.ridge_grad_sample`'s op order exactly
 (gradient, step, ``where``-mask), matching the exact-RNG scan engine.
 
-``interpret=True`` (the CPU path; also CI) evaluates the kernel with
-the Pallas interpreter and switches the lane dot to ``jnp.einsum`` —
-bitwise-identical to the reference's vmapped ``jnp.dot`` — while the
-compiled TPU path keeps the Mosaic-friendly multiply-reduce form.
+The lane dot ``w^T x`` is :func:`repro.core.pipeline.ridge_dot` in the
+form the calling solve chose for all its engines.
 """
 from __future__ import annotations
 
@@ -42,30 +40,40 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core.pipeline import ridge_dot
+
+
+#: The one-hot gather must reproduce ``Xs[ix]`` exactly.  The TPU's
+#: default f32 matmul rounds its operands to bfloat16; HIGHEST keeps
+#: every f32 bit of the gathered rows.
+_EXACT = jax.lax.Precision.HIGHEST
+
+#: Block index 0 as int32: the serving solve calls the kernel under
+#: ``jax.enable_x64``, where a bare ``0`` would be int64, which Mosaic
+#: cannot lower.
+_I0 = np.int32(0)
+
 
 def _mc_ridge_kernel(xs_ref, ys_ref, ix_ref, m_ref, w_ref, o_ref, *,
                      slab: int, n: int, alpha: float, lam: float,
-                     fused: bool, mosaic_dot: bool):
+                     fused: bool, ordered: bool):
     Xs = xs_ref[...]                                   # (d, n) f32
     ys = ys_ref[...]                                   # (1, n) f32
     c_reg = np.float32(2.0 * alpha * lam / n)
     c_2a = np.float32(-2.0 * alpha)
 
-    def lane_dot(W, xr):
-        if mosaic_dot:  # lane-aligned multiply-reduce (compiled TPU path)
-            return jnp.sum(W * xr, axis=0, keepdims=True)
-        # interpret path: bitwise == the reference's vmapped jnp.dot
-        return jnp.einsum("dl,dl->l", W, xr)[None, :]
-
     def body(j, W):
         bl = W.shape[1]
-        ixr = pl.load(ix_ref, (pl.ds(j, 1), slice(None)))   # (1, bl) i32
-        mr = pl.load(m_ref, (pl.ds(j, 1), slice(None)))     # (1, bl) f32
+        ixr = ix_ref[pl.ds(j, 1), :]                        # (1, bl) i32
+        mr = m_ref[pl.ds(j, 1), :]                          # (1, bl) f32
         iota = jax.lax.broadcasted_iota(jnp.int32, (n, bl), 0)
         oh = (iota == ixr).astype(jnp.float32)              # (n, bl)
-        xr = jnp.dot(Xs, oh, preferred_element_type=jnp.float32)  # (d, bl)
-        yr = jnp.dot(ys, oh, preferred_element_type=jnp.float32)  # (1, bl)
-        dot = lane_dot(W, xr)
+        xr = jnp.dot(Xs, oh, precision=_EXACT,
+                     preferred_element_type=jnp.float32)    # (d, bl)
+        yr = jnp.dot(ys, oh, precision=_EXACT,
+                     preferred_element_type=jnp.float32)    # (1, bl)
+        dot = ridge_dot(W, xr, axis=0, keepdims=True,
+                        ordered=ordered)                    # (1, bl)
         if fused:
             c1 = 1.0 - mr * c_reg
             c2 = mr * c_2a * (dot - yr)
@@ -73,20 +81,24 @@ def _mc_ridge_kernel(xs_ref, ys_ref, ix_ref, m_ref, w_ref, o_ref, *,
         g = 2.0 * (dot - yr) * xr + 2.0 * lam / n * W
         return jnp.where(mr > 0.0, W - alpha * g, W)
 
-    o_ref[...] = jax.lax.fori_loop(0, slab, body, w_ref[...])
+    o_ref[...] = jax.lax.fori_loop(np.int32(0), np.int32(slab), body,
+                                   w_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "lam", "fused",
-                                             "interpret", "block_l"))
+                                             "interpret", "ordered",
+                                             "block_l"))
 def mc_ridge_slab(W, Xs, ys, ix, m, *, alpha: float, lam: float,
                   fused: bool, interpret: bool = False,
-                  block_l: int = 128):
+                  ordered: bool = True, block_l: int = 128):
     """Advance all lanes through one slab of update slots.
 
     ``W``: (L, d) f32 per-lane weights; ``Xs``: (n, d) f32 permuted
     training rows; ``ys``: (n,) f32 targets; ``ix``: (slab, L) int32
     sampled row per (slot, lane); ``m``: (slab, L) f32, 1.0 where the
     lane updates at that slot.  Returns the updated (L, d) weights.
+    ``ordered`` is :func:`~repro.core.pipeline.ridge_dot`'s lane-dot form;
+    Mosaic lowers only the ordered one.
     """
     L, d = W.shape
     n = Xs.shape[0]
@@ -101,18 +113,18 @@ def mc_ridge_slab(W, Xs, ys, ix, m, *, alpha: float, lam: float,
 
     kernel = functools.partial(
         _mc_ridge_kernel, slab=slab, n=n, alpha=float(alpha),
-        lam=float(lam), fused=fused, mosaic_dot=not interpret)
+        lam=float(lam), fused=fused, ordered=ordered)
     out = pl.pallas_call(
         kernel,
         grid=(lp // block_l,),
         in_specs=[
-            pl.BlockSpec((d, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((slab, block_l), lambda i: (0, i)),
-            pl.BlockSpec((slab, block_l), lambda i: (0, i)),
-            pl.BlockSpec((d, block_l), lambda i: (0, i)),
+            pl.BlockSpec((d, n), lambda i: (_I0, _I0)),
+            pl.BlockSpec((1, n), lambda i: (_I0, _I0)),
+            pl.BlockSpec((slab, block_l), lambda i: (_I0, i)),
+            pl.BlockSpec((slab, block_l), lambda i: (_I0, i)),
+            pl.BlockSpec((d, block_l), lambda i: (_I0, i)),
         ],
-        out_specs=pl.BlockSpec((d, block_l), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((d, block_l), lambda i: (_I0, i)),
         out_shape=jax.ShapeDtypeStruct((d, lp), jnp.float32),
         interpret=interpret,
     )(Xs.T.astype(jnp.float32), ys[None, :].astype(jnp.float32),

@@ -1,8 +1,9 @@
 """Jit'd wrappers exposing the Pallas kernels in model-layout form.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the kernel
-body executes in Python for correctness validation; on TPU the same code
-compiles to Mosaic.  ``interpret`` defaults to the current backend.
+``interpret`` defaults to the backend: on the CPU (the test suite) the
+kernel bodies run under the Pallas interpreter; on any other backend they
+compile with Mosaic for the TPU.  Nothing falls back to the interpreter
+on an accelerator unasked — a backend Mosaic cannot target fails.
 """
 from __future__ import annotations
 
@@ -14,7 +15,7 @@ from repro.kernels.ssd_scan import ssd_scan_fwd
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
@@ -36,5 +37,8 @@ def ssd_scan(x, dt, a, b, c, *, chunk=256, interpret=None):
     if g != h:
         b = jnp.repeat(b, h // g, axis=2)
         c = jnp.repeat(c, h // g, axis=2)
-    y, state = ssd_scan_fwd(x, dt, a, b, c, chunk=chunk, interpret=interpret)
-    return y.astype(x.dtype), state
+    heads_first = lambda t: jnp.moveaxis(t, 2, 1)
+    y, state = ssd_scan_fwd(heads_first(x), jnp.moveaxis(dt, 1, 2)[:, :, None],
+                            a, heads_first(b), heads_first(c), chunk=chunk,
+                            interpret=interpret)
+    return jnp.moveaxis(y, 1, 2).astype(x.dtype), state

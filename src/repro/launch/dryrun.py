@@ -27,6 +27,10 @@ from repro.models import model as model_mod
 from repro.optim.optimizers import make_optimizer
 
 
+#: The chip the dry-run's fake CPU devices stand in for (a v5e pod).
+TARGET_KIND = "TPU v5 lite"
+
+
 def _shardings(mesh, specs):
     return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                         is_leaf=lambda x: isinstance(x, P))
@@ -65,14 +69,14 @@ def build_lowering(arch: str, shape_name: str, mesh, *, optimizer="adamw"):
         out_sh = (_shardings(mesh, pspecs), _shardings(mesh, ospecs),
                   {"loss": NamedSharding(mesh, P())})
         step_abs = jax.ShapeDtypeStruct((), jax.numpy.int32)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step_fn, in_shardings=in_sh, out_shardings=out_sh,
                               donate_argnums=(0, 1)).lower(
                 params_abs, opt_abs, step_abs, batch_abs)
     elif shape.kind == "prefill":
         step_fn = model_mod.make_prefill_step(cfg)
         in_sh = (_shardings(mesh, pspecs), _shardings(mesh, batch_specs))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step_fn, in_shardings=in_sh).lower(
                 params_abs, batch_abs)
     else:  # decode
@@ -81,7 +85,7 @@ def build_lowering(arch: str, shape_name: str, mesh, *, optimizer="adamw"):
         step_fn = model_mod.make_decode_step(cfg, shape)
         in_sh = (_shardings(mesh, pspecs), _shardings(mesh, cspecs),
                  _shardings(mesh, batch_specs))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step_fn, in_shardings=in_sh,
                               donate_argnums=(1,)).lower(
                 params_abs, cache_abs, batch_abs)
@@ -114,7 +118,8 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, out_dir: str = None,
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
     hlo = compiled.as_text()
-    roof = analyze(cost, hlo, n_chips=n_chips, model_flops=mflops)
+    roof = analyze(cost, hlo, n_chips=n_chips, model_flops=mflops,
+                   device_kind=TARGET_KIND)
 
     mem_rec = {
         k: int(getattr(mem, k))

@@ -254,6 +254,8 @@ def serve(requests: Sequence[Scenario], *, planner: FleetPlanner,
                         pad_to=pad_to, objective=objs[idxs[0]],
                         grid_mode=modes[idxs[0]])
                 except Exception:
+                    if fallback_obj is None:
+                        raise  # no chaos, no budget: a failure is a fault
                     # one retry (the fault draw advances, so a transient
                     # fault clears), then degrade to the bound fallback
                     try:
@@ -416,4 +418,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -18,7 +18,7 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict
 
-from repro.launch.mesh import HBM_BANDWIDTH, ICI_BANDWIDTH, PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -116,19 +116,21 @@ class Roofline:
 
 
 def analyze(cost: dict, hlo_text: str, *, n_chips: int,
-            model_flops: float) -> Roofline:
+            model_flops: float, device_kind: str) -> Roofline:
     """cost: raw compiled.cost_analysis() (recorded for reference only — it
     counts while bodies once); the roofline terms come from the while-aware
-    HLO analyzer (repro.launch.hlo_cost)."""
+    HLO analyzer (repro.launch.hlo_cost), over the peaks of one
+    ``device_kind`` chip (:func:`repro.launch.mesh.chip_peaks`)."""
     from repro.launch.hlo_cost import analyze_hlo
 
+    peaks = chip_peaks(device_kind)
     hc = analyze_hlo(hlo_text)
     flops = hc.flops
     hbm = hc.bytes
 
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = hbm / HBM_BANDWIDTH
-    collective_s = hc.coll_bytes / ICI_BANDWIDTH
+    compute_s = flops / peaks.flops_bf16
+    memory_s = hbm / peaks.hbm_bandwidth
+    collective_s = hc.coll_bytes / peaks.ici_bandwidth
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     total_flops = flops * n_chips

@@ -216,7 +216,15 @@ def run_service(args) -> int:
                    if service.journal.rotations else "")
         print(f"journal: {service.journal.emitted} events appended to "
               f"{args.journal}{rotated}")
-    return 0 if (post == 0 and n_failed == 0) else 1
+    # without injected faults or a budget nothing may degrade: a rung
+    # other than "full" then means a real solve failed
+    n_unplanned = 0 if (args.chaos_spec or args.budget_ms > 0) else sum(
+        r.fallback != "full" for r in records)
+    if n_unplanned:
+        print(f"error: {n_unplanned} requests answered by the degradation "
+              "ladder with no faults injected and no budget set — see "
+              "the journal's solve_failed events", file=sys.stderr)
+    return 0 if (post == 0 and n_failed == 0 and n_unplanned == 0) else 1
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
@@ -337,4 +345,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -3,7 +3,7 @@
 ``maybe_constrain(x, spec_candidates_per_dim)`` applies a
 ``with_sharding_constraint`` built from per-dim candidate axis lists, using
 the first candidate whose mesh-axis product divides the dim.  No-op outside
-a mesh context (CPU unit tests) — the model code stays mesh-agnostic.
+a ``jax.set_mesh`` context (CPU unit tests) — the model code stays mesh-agnostic.
 
 GSPMD usually propagates shardings fine; the hints exist for the few ops
 that block propagation (scatter/argsort in the MoE dispatch, kv-group
@@ -22,11 +22,8 @@ BATCH = ("pod_data",)  # sentinel: the (pod, data) batch axes
 
 
 def _mesh():
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
+    """The mesh set by ``jax.set_mesh``, or ``None`` outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else mesh
 
 

@@ -17,8 +17,7 @@ same moment.
 
 :func:`profile_capture` is the opt-in ``jax.profiler`` hook
 (``--profile-dir`` on the serve CLI): a no-op unless a directory is
-given, import-guarded so environments without the profiler plugin still
-serve.
+given.
 """
 from __future__ import annotations
 
@@ -26,6 +25,8 @@ import threading
 from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Dict, Iterator, Optional
+
+import jax
 
 _LOCK = threading.Lock()
 _GLOBAL = {"device_s": 0.0, "host_s": 0.0, "calls": 0}
@@ -95,21 +96,14 @@ def solve_delta() -> Iterator[SolveDelta]:
 def profile_capture(profile_dir: Optional[str]) -> Iterator[None]:
     """Wrap a block in a ``jax.profiler`` trace written to
     ``profile_dir`` (view with TensorBoard / Perfetto).  Falsy dir ->
-    no-op; a missing/broken profiler degrades to a no-op rather than
-    taking the service down with it."""
+    no-op.  A profiler that fails to start or stop raises: a trace that
+    was asked for and silently not written would be read as one that
+    saw nothing."""
     if not profile_dir:
         yield
         return
-    try:
-        from jax import profiler
-        profiler.start_trace(profile_dir)
-    except Exception:
-        yield
-        return
+    jax.profiler.start_trace(profile_dir)
     try:
         yield
     finally:
-        try:
-            profiler.stop_trace()
-        except Exception:
-            pass
+        jax.profiler.stop_trace()

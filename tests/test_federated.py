@@ -9,6 +9,7 @@ import pytest
 
 from repro.core import (ErasureLink, GilbertElliottLink, IdealLink,
                         Scenario)
+from repro.core.bounds import corollary1_bound
 from repro.core.multidevice import (MultiDeviceSchedule, plan_multi_device,
                                     split_samples)
 from repro.core.scenario import RidgeTask
@@ -177,24 +178,81 @@ if HAVE_HYPOTHESIS:
     @given(pop=st.lists(_h_scenario(), min_size=2, max_size=8),
            frac=st.floats(0.2, 2.5))
     def test_plan_round_property_matches_references(pop, frac):
-        """ISSUE acceptance: participant set + per-participant (rate,
-        n_c) argmin-identical to the numpy reference AND the exponential
-        brute force on randomly drawn mixed-link populations."""
+        """Participant set + per-participant (rate, n_c) argmin-identical
+        to the numpy reference AND the exponential brute force on
+        randomly drawn mixed-link populations (a pick may differ only at
+        a float64 near-tie, see :func:`_assert_same_or_near_tie`)."""
         deadline = frac * float(np.median([sc.N for sc in pop]))
-        planner = RoundPlanner(grid_size=GRID)
-        plan = planner.plan_round(pop, CONSTS, deadline=deadline,
-                                  pad_to=8)       # one compiled shape
-        ref = plan_round_reference(pop, CONSTS, deadline=deadline,
-                                   grid_size=GRID)
-        assert np.array_equal(plan.participants, ref.participants)
-        assert plan.k_best == ref.k_best
-        assert np.array_equal(plan.n_c, ref.n_c)
-        assert np.array_equal(plan.rate, ref.rate)
-        bf = plan_round_bruteforce(pop, CONSTS, deadline=deadline,
-                                   grid_size=GRID)
-        rec = plan.record()
+        _assert_round_matches_references(pop, deadline)
+
+
+#: Largest gap, in ulps, between numpy's bounds at two picks that may
+#: both stand as a device's optimum.
+TIE_ULPS = 2
+
+
+def _assert_same_or_near_tie(sc, deadline, got, want):
+    """A device's pick ``got = (n_c, rate)`` is ``want``, or numpy's bound
+    scores the two within ``TIE_ULPS`` of each other.
+
+    Near-ties are real: at rate 3 with no overhead, a block shorter than
+    one update (``n_c < 3 tau_p``) runs no update before the last block
+    lands, and scores what ``n_c = N`` scores up to the rounding of
+    ``ceil(B_d) / B_d``.  Below an ulp or two the order of such points
+    is the arithmetic's, not the bound's: the jitted bound takes
+    ``exp``/``log`` where numpy takes ``**``, and numpy's vectorised and
+    scalar calls may round differently too.  In 3,000 random populations
+    the kernel picked another point than the reference for 59 devices:
+    at 41 numpy's scalar bound ties exactly, at 18 it is 1 ulp apart."""
+    if got == want:
+        return
+    at = [float(corollary1_bound(
+        np.asarray([float(n_c)]), N=sc.N, T=deadline,
+        n_o=float(sc.effective_overhead(n_c, rate)), tau_p=sc.tau_p,
+        consts=CONSTS)[0]) for n_c, rate in (got, want)]
+    assert abs(at[0] - at[1]) <= TIE_ULPS * np.spacing(max(at)), \
+        (got, want, at)
+
+
+def _assert_round_matches_references(pop, deadline):
+    """The kernel against the numpy reference and the exponential brute
+    force: participants, ``K`` and eligibility exactly, and each device's
+    pick exactly except at a near-tie."""
+    plan = RoundPlanner(grid_size=GRID).plan_round(
+        pop, CONSTS, deadline=deadline, pad_to=8)   # one compiled shape
+    ref = plan_round_reference(pop, CONSTS, deadline=deadline,
+                               grid_size=GRID)
+    assert np.array_equal(plan.participants, ref.participants)
+    assert plan.k_best == ref.k_best
+    assert np.array_equal(plan.eligible, ref.eligible)
+    for i, sc in enumerate(pop):
+        if ref.eligible[i]:
+            _assert_same_or_near_tie(
+                sc, deadline, (int(plan.n_c[i]), float(plan.rate[i])),
+                (int(ref.n_c[i]), float(ref.rate[i])))
+    bf = plan_round_bruteforce(pop, CONSTS, deadline=deadline,
+                               grid_size=GRID)
+    for rec in (plan.record(), ref.record()):
         assert rec.participants == bf.participants
-        assert rec.n_c == bf.n_c and rec.rate == bf.rate
+        for j, i in enumerate(rec.participants):
+            _assert_same_or_near_tie(pop[i], deadline,
+                                     (rec.n_c[j], rec.rate[j]),
+                                     (bf.n_c[j], bf.rate[j]))
+
+
+#: A random population at a near-tie: at rate 3, numpy's vectorised bound
+#: puts ``n_c = 117`` 1 ulp below ``n_c = 1``; the kernel and numpy's
+#: scalar bound (the brute force) put them the other way round.
+_TIE_POPULATION = [Scenario(N=117, T=117.0, n_o=0.0, tau_p=2.0,
+                            link=IdealLink(rates=RATES))] * 2
+_TIE_DEADLINE = 1601.341506031503
+
+
+def test_plan_round_float64_tie_matches_references():
+    plan = RoundPlanner(grid_size=GRID).plan_round(
+        _TIE_POPULATION, CONSTS, deadline=_TIE_DEADLINE, pad_to=8)
+    assert list(plan.n_c) == [1, 1]     # the case this test exists for
+    _assert_round_matches_references(_TIE_POPULATION, _TIE_DEADLINE)
 
 
 # ---------------------------------------------------------------------------

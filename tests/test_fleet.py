@@ -170,8 +170,8 @@ def test_plan_batch_accepts_scenario_list_and_shared_grid():
 def test_bounds_jax_port_matches_numpy_reference():
     """The jnp port agrees with the numpy evaluator on a broadcast grid
     including negative effective overheads and both regimes."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     rng = np.random.default_rng(3)
     n_c = np.maximum(rng.uniform(1, 3e4, (5, 64)), 1.0)
@@ -186,7 +186,7 @@ def test_bounds_jax_port_matches_numpy_reference():
                                   D=2.0, alpha=1e-6)):     # contraction ~ 1
         ref = corollary1_bound(n_c, N=18576, T=1.5 * 18576, n_o=n_o,
                                tau_p=1.0, consts=consts)
-        with enable_x64():
+        with jax.enable_x64(True):
             got = np.asarray(corollary1_bound_jax(
                 jnp.asarray(n_c), N=18576.0, T=1.5 * 18576, n_o=jnp.asarray(n_o),
                 tau_p=1.0, sigma=consts.variance_floor, e0=consts.init_gap,
@@ -619,9 +619,9 @@ scs = [Scenario(N=int(n), T=1.3 * n, n_o=float(o), tau_p=1.0,
                        (20, 90, 45, 150, 60, 10, 120, 75))]
 batch = ScenarioBatch.from_scenarios(scs)   # S = 8 divides 4 devices
 consts = default_consts()
-sharded = FleetPlanner(grid_size=8, shard=True).plan_batch(
+sharded = FleetPlanner(grid_size=8, shard=True, mc_impl=MC_IMPL).plan_batch(
     batch, consts, objective=mc)
-local = FleetPlanner(grid_size=8, shard=False).plan_batch(
+local = FleetPlanner(grid_size=8, shard=False, mc_impl=MC_IMPL).plan_batch(
     batch, consts, objective=mc)
 np.testing.assert_array_equal(sharded.n_c, local.n_c)
 np.testing.assert_array_equal(sharded.rate, local.rate)
@@ -631,9 +631,12 @@ print("MC-SHARDED-OK")
 """
 
 
-def test_montecarlo_sharded_kernel_matches_unsharded():
+@pytest.mark.parametrize("mc_impl", ["scan", "pallas"])
+def test_montecarlo_sharded_kernel_matches_unsharded(mc_impl):
     """ISSUE tentpole: the Monte-Carlo objective kernel lays its
     (S, R, G) simulation-lane axis over the forced 4-device mesh
     (scenario-sharded inputs + lane-axis sharding constraint) and its
-    plans match the unsharded kernel argmin-exactly."""
-    _run_forced_device_script(_MC_SHARD_SCRIPT, "MC-SHARDED-OK")
+    plans match the unsharded kernel argmin-exactly — with the Pallas
+    slab kernel too, which runs per device under ``shard_map``."""
+    _run_forced_device_script(f"MC_IMPL = {mc_impl!r}\n" + _MC_SHARD_SCRIPT,
+                              "MC-SHARDED-OK")

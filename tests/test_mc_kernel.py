@@ -324,14 +324,16 @@ def test_coarse_horizon_cap_is_a_timeline_prefix():
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("mc_impl", ["scan", "pallas"])
 @pytest.mark.parametrize("hints", [{}, dict(fine_radius=6,
                                             coarse_updates=8)])
-def test_multi_level_warmup_is_exhaustive(hints):
+def test_multi_level_warmup_is_exhaustive(hints, mc_impl):
     """AOT warmup covers every shape the multi-level schedule can hit —
     including the horizon-capped coarse stages and the widened fine
     window: zero post-warmup traces for a planned batch (the serving
-    SLO).  The batch is a pow2 length — warmup pads to the pow2 / bucket
-    signature exactly like the serving layer's request batches."""
+    SLO), for either engine.  The batch is a pow2 length — warmup pads to
+    the pow2 / bucket signature exactly like the serving layer's request
+    batches."""
     X, y = _ridge_data()
     fast = MonteCarloObjective(X=X, y=y, n_runs=2, alpha=1e-3, seed=0,
                                grid_points=48, crn=True, coarse_seeds=1,
@@ -339,7 +341,8 @@ def test_multi_level_warmup_is_exhaustive(hints):
                                **hints)
     scs = _scenarios(4)
     planner = FleetPlanner(objective=fast, grid_size=48,
-                           grid_mode="refine", pow2_refine_widths=True)
+                           grid_mode="refine", pow2_refine_widths=True,
+                           mc_impl=mc_impl)
     assert planner.warm(scs, CONSTS) > 0
     with trace_delta() as traces:
         plan = planner.plan_batch(scs, CONSTS)

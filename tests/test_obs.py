@@ -504,3 +504,21 @@ def test_stats_recorder_concurrent_record_and_snapshot():
     assert snap.n_planned == 8000
     hist = LogHistogram.from_dict(snap.latency_hist)
     assert hist.count == 8000             # no lost updates
+
+
+def test_profile_capture_raises_when_the_profiler_fails(tmp_path,
+                                                        monkeypatch):
+    """A trace that was asked for and not written must not pass silently;
+    no directory means no profiler at all."""
+    import jax
+    from repro.obs.runtime import profile_capture
+
+    def refuse(_dir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with profile_capture(None):
+        pass
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with profile_capture(str(tmp_path)):
+            pass

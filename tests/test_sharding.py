@@ -139,7 +139,8 @@ from repro.optim.optimizers import make_optimizer
 from repro import sharding as shd
 from repro.configs.base import InputShape
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(2, 2)
 cfg = reduced(get_config("llama3.2-1b"))
 shape = InputShape("small", 64, 4, "train")
 params = abstract_params(cfg)
@@ -152,7 +153,7 @@ sh = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                             is_leaf=lambda x: isinstance(x, P))
 step = make_train_step(cfg, opt)
 batch = input_specs(cfg, shape)
-with mesh:
+with jax.set_mesh(mesh):
     lowered = jax.jit(step, in_shardings=(sh(pspecs), sh(ospecs),
                                           NamedSharding(mesh, P()), sh(bspecs))
                       ).lower(params, opt_abs,
@@ -167,3 +168,39 @@ print("MEM", compiled.memory_analysis().temp_size_in_bytes)
                            __import__("os").path.dirname(__file__)))
     assert r.returncode == 0, r.stderr[-3000:]
     assert "MEM" in r.stdout
+
+
+# ---------------------------------------------------------------------------
+# launch helpers: roofline peaks, compile cache
+# ---------------------------------------------------------------------------
+
+
+def test_chip_peaks_are_keyed_by_device_kind():
+    from repro.launch.mesh import chip_peaks
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bandwidth) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no peaks recorded"):
+        chip_peaks("cpu")
+
+
+@pytest.mark.parametrize("env_dir", [None, "named"])
+def test_compile_cache_follows_env_else_checkout(env_dir, tmp_path,
+                                                 monkeypatch):
+    from repro.launch import compile_cache
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs)
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(compile_cache.DEFAULT_DIR)
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          was[1])
+    assert compile_cache.DEFAULT_DIR.name == ".jax_cache"
+    assert (compile_cache.DEFAULT_DIR.parent / "chip_smoke.py").exists()

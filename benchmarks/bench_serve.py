@@ -15,7 +15,7 @@ Exercises :class:`repro.serve.PlanningService` the way production would:
          registry (the same series a Prometheus scrape sees — so the
          gate also validates the export path end to end);
        * per-request phase spans SUM EXACTLY (<= 1 µs) to the reported
-         enqueue-to-plan latency, and the device-fenced solve fraction
+         enqueue-to-plan latency, and the solve fraction
          clears a sanity floor (the spans are attributing real compute,
          not noise);
        * enqueue-to-plan p99 under a generous bound (the flush deadline
@@ -64,7 +64,7 @@ P99_CEILING_S = 2.0
 #: tax would show up here long before it hit 50%)
 THROUGHPUT_FLOOR = 0.5
 #: the spans must attribute REAL device compute: over a whole stream the
-#: fenced solve share of enqueue-to-plan latency cannot round to zero
+#: solve share of enqueue-to-plan latency cannot round to zero
 SOLVE_FRACTION_FLOOR = 1e-3
 #: phase intervals are cut from one monotonic clock: sums are exact up
 #: to float addition error
@@ -148,7 +148,7 @@ def run():
         "zero cumulative batch-wait over a whole stream: spans are not "
         "measuring queueing")
     assert stats.solve_fraction >= SOLVE_FRACTION_FLOOR, (
-        f"device-fenced solve fraction {stats.solve_fraction:.5f} is below "
+        f"solve fraction {stats.solve_fraction:.5f} is below "
         f"{SOLVE_FRACTION_FLOOR} — solve attribution lost the actual "
         "compute")
 

@@ -143,7 +143,7 @@ def _service_health(service, label: str) -> str:
     return (f"{stats.n_planned} planned in {stats.n_batches} batches, "
             f"p50={stats.latency_p50_ms:.3f}ms "
             f"p99={stats.latency_p99_ms:.3f}ms, solve per request's batch "
-            f"{1e3 * ph['solve'] / ph['count']:.3f}ms (fenced "
+            f"{1e3 * ph['solve'] / ph['count']:.3f}ms (device wait "
             f"{1e3 * ph['solve_device'] / ph['count']:.3f}ms), warmup "
             f"{service.warmup_seconds:.3f}s/{service.warmup_traces} traces")
 

@@ -24,7 +24,6 @@ tests pin the planner to both.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -38,10 +37,10 @@ from repro.core.scenario import Scenario
 from repro.federated.round_kernels import round_solve
 from repro.fleet.batch import ScenarioBatch
 from repro.fleet.cache import quantise, scenario_key
-from repro.fleet.objective_kernels import _maybe_shard
+from repro.fleet.objective_kernels import _count_in, _fetch, _maybe_shard
 from repro.fleet.planner import _pad_batch
 from repro.fleet.tracing import trace_delta
-from repro.obs.runtime import record_solve
+from repro.obs.runtime import span
 
 #: The objective token federated cache entries are scoped under — plays
 #: the role ``Objective.cache_token()`` plays for per-device plans, so a
@@ -169,7 +168,9 @@ class RoundPlanner:
         if deadline is None:
             deadline = self.resolve_deadline(population)
         S_real = len(population)
-        batch = ScenarioBatch.from_scenarios(_pad_batch(population, pad_to))
+        with span("planner.build"):
+            batch = ScenarioBatch.from_scenarios(
+                _pad_batch(population, pad_to))
         return self.plan_round_batch(batch, consts, deadline=deadline,
                                      n_real=S_real)
 
@@ -192,6 +193,29 @@ class RoundPlanner:
         ``grid_size``); ``deadline`` defaults to the tightest real
         per-device ``T`` in the batch.
         """
+        with span("planner.build"):
+            arrays, deadline, S_real = self._round_arrays(
+                batch, consts, deadline, n_real, grid)
+        with span("planner.dispatch"):
+            fn = round_solve()
+            _count_in(len(arrays) + 4)
+            with jax.enable_x64(True):
+                if self.shard:
+                    arrays = _maybe_shard(arrays, len(batch))
+                out = fn(T=np.float64(deadline),
+                         sigma=np.float64(consts.variance_floor),
+                         e0=np.float64(consts.init_gap),
+                         contraction=np.float64(consts.contraction),
+                         **arrays)
+        res = _fetch(out)
+        with span("planner.records"):
+            return self._unpad(res, deadline, S_real)
+
+    def _round_arrays(self, batch: ScenarioBatch, consts: BoundConstants,
+                      deadline: Optional[float], n_real: Optional[int],
+                      grid: Optional[np.ndarray]):
+        """The round kernel's array arguments, the resolved deadline and
+        the real population size (see :meth:`plan_round_batch`)."""
         consts.validate()
         S = len(batch)
         n_real = S if n_real is None else int(n_real)
@@ -209,9 +233,8 @@ class RoundPlanner:
         if grid.ndim != 2 or grid.shape[0] != S:
             raise ValueError(
                 f"grid has shape {grid.shape}, want ({S}, G)")
-        S_real = n_real
         valid = np.zeros(S, bool)
-        valid[:S_real] = True
+        valid[:n_real] = True
         arrays = {
             "N": np.asarray(batch.N, np.int64),
             "union_no": batch.union_overhead,
@@ -223,20 +246,10 @@ class RoundPlanner:
             "link_params": np.asarray(batch.link_params, np.float64),
             "valid": valid,
         }
-        fn = round_solve()
-        with jax.enable_x64(True):
-            if self.shard:
-                arrays = _maybe_shard(arrays, S)
-            t0 = time.perf_counter()
-            out = fn(T=np.float64(deadline),
-                     sigma=np.float64(consts.variance_floor),
-                     e0=np.float64(consts.init_gap),
-                     contraction=np.float64(consts.contraction), **arrays)
-            jax.block_until_ready(out)
-            t1 = time.perf_counter()
-            res = {k: np.asarray(v) for k, v in out.items()}
-            record_solve(t1 - t0, time.perf_counter() - t1)
+        return arrays, deadline, n_real
 
+    @staticmethod
+    def _unpad(res: dict, deadline: float, S_real: int) -> RoundPlan:
         # unpad: pad lanes are never eligible, so the eligible prefix of
         # the sort consists of real devices only — dropping pad indices
         # from `order` keeps the participant prefix intact

@@ -64,7 +64,7 @@ def _build_round_solve(branches):
     """Jit the round solve closed over the link-kernel branch table."""
 
     @jax.jit
-    def _solve(N, T, union_no, tau_p, rates, rate_mask, grid,
+    def federated_round_solve(N, T, union_no, tau_p, rates, rate_mask, grid,
                link_model_id, link_params, valid, sigma, e0, contraction):
         # runs once per TRACE — the serving retrace audit
         record_trace(("federated",) + tuple(grid.shape))
@@ -143,7 +143,7 @@ def _build_round_solve(branches):
             "eligible": eligible,
         }
 
-    return _solve
+    return federated_round_solve
 
 
 @lru_cache(maxsize=4)

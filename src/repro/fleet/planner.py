@@ -45,6 +45,7 @@ from repro.fleet.batch import ScenarioBatch
 from repro.fleet.cache import PlanCache
 from repro.fleet.objective_kernels import fleet_solve, pow2ceil
 from repro.fleet.tracing import trace_delta
+from repro.obs.runtime import count, span
 
 #: Valid ``FleetPlanner.grid_mode`` values: ``"dense"`` (single-pass, the
 #: reference semantics and the documented escape hatch) and ``"refine"``
@@ -276,48 +277,49 @@ class FleetPlanner:
         scenario's chosen rate (ascending in ``n_c``) rather than the
         full dense grid.
         """
-        consts.validate()
-        objective = self._resolve_objective(objective)
-        mode = self._resolve_grid_mode(grid_mode)
-        if not isinstance(batch, ScenarioBatch):
-            batch = ScenarioBatch.from_scenarios(list(batch))
-        S = len(batch)
-        if grid is None:
-            grid = self._default_grid(batch, objective)
-        else:
-            grid = np.asarray(grid, np.int64)
-            if grid.ndim == 1:
-                grid = np.broadcast_to(grid, (S, grid.shape[0]))
-            if grid.shape[0] != S:
-                raise ValueError(
-                    f"grid has leading dim {grid.shape[0]}, want {S}")
-
-        solve = fleet_solve(objective)
-        arrays = self._kernel_arrays(solve, batch, grid)
+        with span("planner.build"):
+            consts.validate()
+            objective = self._resolve_objective(objective)
+            mode = self._resolve_grid_mode(grid_mode)
+            if not isinstance(batch, ScenarioBatch):
+                batch = ScenarioBatch.from_scenarios(list(batch))
+            S = len(batch)
+            if grid is None:
+                grid = self._default_grid(batch, objective)
+            else:
+                grid = np.asarray(grid, np.int64)
+                if grid.ndim == 1:
+                    grid = np.broadcast_to(grid, (S, grid.shape[0]))
+                if grid.shape[0] != S:
+                    raise ValueError(
+                        f"grid has leading dim {grid.shape[0]}, want {S}")
+            solve = fleet_solve(objective)
+            arrays = self._kernel_arrays(solve, batch, grid)
         out = None
         if mode == "refine":
             out, fine_grid = self._refine_solve(solve, arrays, consts,
                                                 batch, objective, grid)
         if out is None:  # dense mode, or refinement fell back
             out = solve(arrays, consts, self.shard, batch)
-            fine_grid = np.asarray(grid)
-
-        D = batch.n_devices
-        num = np.maximum(batch.N * out["n_o_eff"], 0.0)
-        den = batch.T - batch.N
-        # regime boundary N * n_o_eff / (T - N); T <= N means the full set
-        # can never arrive — clamp to +inf explicitly (matching the scalar
-        # boundary_n_c) so no inf/NaN arithmetic can leak into records
-        ratio = num / np.where(den > 0.0, den, 1.0)
-        boundary = np.where(den > 0.0, ratio, np.inf)
-        return FleetPlan(
-            n_c=out["n_c"], rate=out["rate"],
-            bound_value=out["bound_value"], p_err=out["p_err"],
-            n_o_eff=out["n_o_eff"], full_transfer=out["full_transfer"],
-            boundary=boundary,
-            n_c_per_device=np.maximum(1, out["n_c"] // D),
-            grid=fine_grid, bound_grid=out["bound_grid"],
-            objective=objective.objective_id)
+            fine_grid = grid
+        with span("planner.records"):
+            D = batch.n_devices
+            num = np.maximum(batch.N * out["n_o_eff"], 0.0)
+            den = batch.T - batch.N
+            # regime boundary N * n_o_eff / (T - N); T <= N means the full
+            # set can never arrive — clamp to +inf explicitly (matching the
+            # scalar boundary_n_c) so no inf/NaN arithmetic can leak into
+            # records
+            ratio = num / np.where(den > 0.0, den, 1.0)
+            boundary = np.where(den > 0.0, ratio, np.inf)
+            return FleetPlan(
+                n_c=out["n_c"], rate=out["rate"],
+                bound_value=out["bound_value"], p_err=out["p_err"],
+                n_o_eff=out["n_o_eff"], full_transfer=out["full_transfer"],
+                boundary=boundary,
+                n_c_per_device=np.maximum(1, out["n_c"] // D),
+                grid=np.asarray(fine_grid), bound_grid=out["bound_grid"],
+                objective=objective.objective_id)
 
     def _kernel_arrays(self, solve, batch, grid):
         """The solve's input arrays, with the Monte-Carlo engine for a
@@ -367,54 +369,55 @@ class FleetPlanner:
         a fraction of the scan cost, and the wide full-horizon fine
         window absorbs the residual center drift.
         """
-        S, G = grid.shape
-        hints = refine_hints_for(objective)
-        if G < max(2, hints.min_grid):
-            return None, None
-        schedulable = getattr(solve, "supports_mc_impl", False)
-        ml = hints.coarse_strides if schedulable else None
-        if ml is not None:
-            ml = tuple(max(2, min(int(s), G - 1)) for s in ml)
-        hz = hints.coarse_updates if schedulable else None
-        # an objective's explicit stride hint is honoured as-is (clamped
-        # to the grid); only the automatic work-minimising default applies
-        stride = ((hints.fine_radius if schedulable else None)
-                  or (ml[-1] if ml else
-                      hints.stride or int(round(np.sqrt(G / 2.0)))))
-        stride = max(2, min(int(stride), G - 1))
-        cpos = coarse_indices(G, ml[0] if ml else stride)
-        if cpos.size < 4:
-            return None, None
-        guided = schedulable and hints.coarse_seeds == 0
-        K = hints.refine_rates if schedulable else None
-        R = int(np.asarray(arrays["rates"]).shape[1])
-        prune = K is not None and K < R
-        scheduled = (guided or prune or ml is not None or hz is not None
-                     or (schedulable and bool(hints.coarse_seeds
-                                              or hints.fine_radius)))
+        with span("planner.refine_host"):
+            S, G = grid.shape
+            hints = refine_hints_for(objective)
+            if G < max(2, hints.min_grid):
+                return None, None
+            schedulable = getattr(solve, "supports_mc_impl", False)
+            ml = hints.coarse_strides if schedulable else None
+            if ml is not None:
+                ml = tuple(max(2, min(int(s), G - 1)) for s in ml)
+            hz = hints.coarse_updates if schedulable else None
+            # an objective's explicit stride hint is honoured as-is (clamped
+            # to the grid); only the automatic work-minimising default applies
+            stride = ((hints.fine_radius if schedulable else None)
+                      or (ml[-1] if ml else
+                          hints.stride or int(round(np.sqrt(G / 2.0)))))
+            stride = max(2, min(int(stride), G - 1))
+            cpos = coarse_indices(G, ml[0] if ml else stride)
+            if cpos.size < 4:
+                return None, None
+            guided = schedulable and hints.coarse_seeds == 0
+            K = hints.refine_rates if schedulable else None
+            R = int(np.asarray(arrays["rates"]).shape[1])
+            prune = K is not None and K < R
+            scheduled = (guided or prune or ml is not None or hz is not None
+                         or (schedulable and bool(hints.coarse_seeds
+                                                  or hints.fine_radius)))
 
-        if hints.tail_blocks:
-            # first dense index inside the guarded sawtooth tail
-            # (N / n_c <= tail_blocks); rows of `grid` are ascending
-            tail = np.sum(
-                grid.astype(np.int64) * int(hints.tail_blocks)
-                < batch.N[:, None], axis=1)
-        else:
-            tail = None
-        # tail windows vary per scenario: round the padded width up to a
-        # multiple of 8 so a request stream compiles O(G / 8) fine-pass
-        # shapes, not one per distinct tail length
-        pad_multiple = 8 if tail is not None else 1
-        # upper-bound the fine width BEFORE the coarse solve: bracket +
-        # longest tail suffix (centers can only merge the two, never
-        # widen them), so an unprofitable batch — e.g. one small-N
-        # scenario whose guarded tail spans most of the log grid — costs
-        # nothing instead of a wasted coarse pass on top of the dense one
-        w_ub = 2 * stride + 1 + (G - int(tail.min()) if tail is not None
-                                 else 0)
-        if not scheduled and \
-                cpos.size + min(G, self._pad_width(w_ub, pad_multiple)) >= G:
-            return None, None  # two passes would outwork the dense solve
+            if hints.tail_blocks:
+                # first dense index inside the guarded sawtooth tail
+                # (N / n_c <= tail_blocks); rows of `grid` are ascending
+                tail = np.sum(
+                    grid.astype(np.int64) * int(hints.tail_blocks)
+                    < batch.N[:, None], axis=1)
+            else:
+                tail = None
+            # tail windows vary per scenario: round the padded width up to a
+            # multiple of 8 so a request stream compiles O(G / 8) fine-pass
+            # shapes, not one per distinct tail length
+            pad_multiple = 8 if tail is not None else 1
+            # upper-bound the fine width BEFORE the coarse solve: bracket +
+            # longest tail suffix (centers can only merge the two, never
+            # widen them), so an unprofitable batch — e.g. one small-N
+            # scenario whose guarded tail spans most of the log grid — costs
+            # nothing instead of a wasted coarse pass on top of the dense one
+            w_ub = 2 * stride + 1 + (G - int(tail.min()) if tail is not None
+                                     else 0)
+            if not scheduled and cpos.size + min(
+                    G, self._pad_width(w_ub, pad_multiple)) >= G:
+                return None, None  # two passes would outwork the dense solve
 
         if guided:
             # bound-guided coarse: the closed-form Corollary-1 solve on
@@ -424,29 +427,33 @@ class FleetPlanner:
                             if k not in ("mc_impl", "mc_seeds")}
             out1 = fleet_solve(BoundObjective())(bound_arrays, consts,
                                                  self.shard, batch)
-            centers = np.asarray(out1["gi_per_rate"], np.int64)
         else:
-            arrays1 = dict(arrays,
-                           grid=np.ascontiguousarray(grid[:, cpos]))
-            if schedulable and hints.coarse_seeds:
-                arrays1["mc_seeds"] = int(hints.coarse_seeds)
-            if hz:
-                arrays1["mc_updates"] = int(hz)
+            with span("planner.refine_host"):
+                arrays1 = dict(arrays,
+                               grid=np.ascontiguousarray(grid[:, cpos]))
+                if schedulable and hints.coarse_seeds:
+                    arrays1["mc_seeds"] = int(hints.coarse_seeds)
+                if hz:
+                    arrays1["mc_updates"] = int(hz)
             out1 = solve(arrays1, consts, self.shard, batch)
-            centers1 = out1.get("gi_per_rate")
-            if centers1 is None:  # pre-refinement custom kernel
-                return None, None
-            centers = cpos[np.asarray(centers1, np.int64)]     # (S, R)
 
-        sel = None
-        if prune and "val_per_rate" in out1:
-            # keep each scenario's top-K rates by the coarse per-rate
-            # minima; ascending index order preserves the reduction's
-            # rate-major tie-breaking among the kept rates
-            vpr = np.asarray(out1["val_per_rate"])
-            sel = np.sort(np.argsort(vpr, axis=1, kind="stable")[:, :K],
-                          axis=1)                              # (S, K)
-            centers = np.take_along_axis(centers, sel, axis=1)
+        with span("planner.refine_host"):
+            if guided:
+                centers = np.asarray(out1["gi_per_rate"], np.int64)
+            else:
+                centers1 = out1.get("gi_per_rate")
+                if centers1 is None:  # pre-refinement custom kernel
+                    return None, None
+                centers = cpos[np.asarray(centers1, np.int64)]  # (S, R)
+            sel = None
+            if prune and "val_per_rate" in out1:
+                # keep each scenario's top-K rates by the coarse per-rate
+                # minima; ascending index order preserves the reduction's
+                # rate-major tie-breaking among the kept rates
+                vpr = np.asarray(out1["val_per_rate"])
+                sel = np.sort(np.argsort(vpr, axis=1, kind="stable")[:, :K],
+                              axis=1)                              # (S, K)
+                centers = np.take_along_axis(centers, sel, axis=1)
 
         if ml is not None:
             # mid coarse stages: re-centre at each finer step inside the
@@ -454,50 +461,54 @@ class FleetPlanner:
             # index sets — clipping at the grid edges keeps the width
             # (hence the compiled shape) data-independent.
             for prev, step in zip(ml, ml[1:]):
-                offs = np.arange(-(prev // step),
-                                 prev // step + 1) * step      # (O,)
-                win = np.clip(centers[:, :, None] + offs, 0, G - 1)
-                arrays_i = dict(arrays, grid=np.ascontiguousarray(
-                    np.take_along_axis(grid[:, None, :], win, axis=2)))
-                if sel is not None:
-                    arrays_i["rates"] = np.ascontiguousarray(
-                        np.take_along_axis(
-                            np.asarray(arrays["rates"]), sel, 1))
-                    arrays_i["rate_mask"] = np.ascontiguousarray(
-                        np.take_along_axis(
-                            np.asarray(arrays["rate_mask"]), sel, 1))
-                if hints.coarse_seeds:
-                    arrays_i["mc_seeds"] = int(hints.coarse_seeds)
-                if hz:
-                    arrays_i["mc_updates"] = int(hz)
+                with span("planner.refine_host"):
+                    offs = np.arange(-(prev // step),
+                                     prev // step + 1) * step      # (O,)
+                    win = np.clip(centers[:, :, None] + offs, 0, G - 1)
+                    arrays_i = dict(arrays, grid=np.ascontiguousarray(
+                        np.take_along_axis(grid[:, None, :], win, axis=2)))
+                    if sel is not None:
+                        arrays_i["rates"] = np.ascontiguousarray(
+                            np.take_along_axis(
+                                np.asarray(arrays["rates"]), sel, 1))
+                        arrays_i["rate_mask"] = np.ascontiguousarray(
+                            np.take_along_axis(
+                                np.asarray(arrays["rate_mask"]), sel, 1))
+                    if hints.coarse_seeds:
+                        arrays_i["mc_seeds"] = int(hints.coarse_seeds)
+                    if hz:
+                        arrays_i["mc_updates"] = int(hz)
                 out_i = solve(arrays_i, consts, self.shard, batch)
-                gi = np.asarray(out_i["gi_per_rate"], np.int64)
-                centers = np.take_along_axis(
-                    win, gi[:, :, None], axis=2)[..., 0]
+                with span("planner.refine_host"):
+                    gi = np.asarray(out_i["gi_per_rate"], np.int64)
+                    centers = np.take_along_axis(
+                        win, gi[:, :, None], axis=2)[..., 0]
 
-        count = refine_window_bounds(centers, stride, G, tail)[-1]
-        W = min(G, self._pad_width(int(count.max()), pad_multiple))
-        if not scheduled and cpos.size + W >= G:
-            return None, None  # the merged windows still cover the grid
+        with span("planner.refine_host"):
+            count_w = refine_window_bounds(centers, stride, G, tail)[-1]
+            W = min(G, self._pad_width(int(count_w.max()), pad_multiple))
+            if not scheduled and cpos.size + W >= G:
+                return None, None  # the merged windows still cover the grid
 
-        if getattr(solve, "supports_refine_windows", False):
-            # fused fine pass: windows are built and gathered on device
-            # from (centers, tail_start); the host only sizes W
-            arrays2 = dict(
-                arrays,
-                centers=np.ascontiguousarray(centers),
-                tail_start=(np.zeros(S, np.int64) + G if tail is None
-                            else np.asarray(tail, np.int64)),
-                refine_stride=stride, refine_width=W)
-        else:  # e.g. the Monte-Carlo kernel: host-built (S, R, W) windows
-            _, win_grid, _ = refine_grid(grid, centers, stride,
-                                         tail_start=tail, width=W)
-            arrays2 = dict(arrays, grid=np.ascontiguousarray(win_grid))
-        if sel is not None:
-            arrays2["rates"] = np.ascontiguousarray(
-                np.take_along_axis(np.asarray(arrays["rates"]), sel, 1))
-            arrays2["rate_mask"] = np.ascontiguousarray(
-                np.take_along_axis(np.asarray(arrays["rate_mask"]), sel, 1))
+            if getattr(solve, "supports_refine_windows", False):
+                # fused fine pass: windows are built and gathered on
+                # device from (centers, tail_start); the host only sizes W
+                arrays2 = dict(
+                    arrays,
+                    centers=np.ascontiguousarray(centers),
+                    tail_start=(np.zeros(S, np.int64) + G if tail is None
+                                else np.asarray(tail, np.int64)),
+                    refine_stride=stride, refine_width=W)
+            else:  # e.g. the Monte-Carlo kernel: host-built (S, R, W)
+                _, win_grid, _ = refine_grid(grid, centers, stride,
+                                             tail_start=tail, width=W)
+                arrays2 = dict(arrays, grid=np.ascontiguousarray(win_grid))
+            if sel is not None:
+                arrays2["rates"] = np.ascontiguousarray(np.take_along_axis(
+                    np.asarray(arrays["rates"]), sel, 1))
+                arrays2["rate_mask"] = np.ascontiguousarray(
+                    np.take_along_axis(np.asarray(arrays["rate_mask"]),
+                                       sel, 1))
         out2 = solve(arrays2, consts, self.shard, batch)
         return out2, np.asarray(out2["sel_grid"])
 
@@ -708,35 +719,45 @@ class FleetPlanner:
             return now
 
         records: List[Optional[PlanRecord]] = [None] * len(scenarios)
+        count("lanes_live", len(scenarios))
         if cache is None:
             t0 = time.perf_counter()
-            fp = self.plan_batch(_pad_batch(scenarios, pad_to), consts,
-                                 objective=objective, grid_mode=mode)
-            out = [fp.record(i) for i in range(len(scenarios))]
+            count("lanes_unique", len(scenarios))
+            with span("planner.build"):
+                padded = _pad_batch(scenarios, pad_to)
+            fp = self.plan_batch(padded, consts, objective=objective,
+                                 grid_mode=mode)
+            with span("planner.records"):
+                out = [fp.record(i) for i in range(len(scenarios))]
             charge("solve_s", t0)
             return out
 
         ctx = self.cache_context(consts, mode)
         miss: "OrderedDict[tuple, List[int]]" = OrderedDict()
         t0 = time.perf_counter()
-        for i, sc in enumerate(scenarios):
-            rec = cache.get(sc, context=ctx, objective=objective)
-            if rec is not None:
-                records[i] = rec
-            else:
-                miss.setdefault(
-                    cache.key(sc, context=ctx, objective=objective),
-                    []).append(i)
+        with span("planner.cache_lookup"):
+            for i, sc in enumerate(scenarios):
+                rec = cache.get(sc, context=ctx, objective=objective)
+                if rec is not None:
+                    records[i] = rec
+                else:
+                    miss.setdefault(
+                        cache.key(sc, context=ctx, objective=objective),
+                        []).append(i)
         t0 = charge("cache_lookup_s", t0)
         if miss:
-            reps = [scenarios[idxs[0]] for idxs in miss.values()]
-            fp = self.plan_batch(_pad_batch(reps, pad_to), consts,
-                                 objective=objective, grid_mode=mode)
-            for j, idxs in enumerate(miss.values()):
-                rec = fp.record(j)
-                cache.put(scenarios[idxs[0]], rec, context=ctx,
-                          objective=objective)
-                for i in idxs:
-                    records[i] = rec
+            count("lanes_unique", len(miss))
+            with span("planner.build"):
+                reps = _pad_batch([scenarios[idxs[0]]
+                                   for idxs in miss.values()], pad_to)
+            fp = self.plan_batch(reps, consts, objective=objective,
+                                 grid_mode=mode)
+            with span("planner.records"):
+                for j, idxs in enumerate(miss.values()):
+                    rec = fp.record(j)
+                    cache.put(scenarios[idxs[0]], rec, context=ctx,
+                              objective=objective)
+                    for i in idxs:
+                        records[i] = rec
             charge("solve_s", t0)
         return records  # type: ignore[return-value]

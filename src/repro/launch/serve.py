@@ -22,7 +22,7 @@ background thread, node-exporter textfile style, plus a final dump);
 lifecycle) to a JSONL file; ``--profile-dir`` wraps the serving stream
 in a ``jax.profiler`` trace.  The final report includes the per-phase
 latency breakdown (batch-wait / pad / cache-lookup / solve / resolve)
-and the device-fenced solve fraction.
+and the solve fraction with the time spent waiting on the device.
 
 Unknown model/objective/grid-mode/policy names exit with code 2 (usage
 error), like the other launch drivers.  The LLM decode driver that
@@ -189,7 +189,7 @@ def run_service(args) -> int:
     print(f"phase breakdown (mean ms/request): {breakdown} "
           f"| latency={means['latency']:.2f}")
     print(f"solve fraction: {stats.solve_fraction:.1%} of enqueue-to-plan "
-          f"latency (device-fenced "
+          f"latency (waiting on the device "
           f"{stats.phases.get('solve_device', 0.0):.3f}s of "
           f"{stats.phases.get('solve', 0.0):.3f}s solve)")
     for (oid, mode, bucket), slot in sorted(stats.buckets.items()):

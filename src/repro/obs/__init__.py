@@ -6,32 +6,34 @@ planner, service, CLIs, benches) can flow through it without cycles:
 
   * :mod:`repro.obs.hist` — log-spaced MERGEABLE histograms (the
     bounded-memory latency representation a fleet of service instances
-    can aggregate by addition) plus the exact-window :class:`Reservoir`;
+    can aggregate by addition);
   * :mod:`repro.obs.spans` — the per-request lifecycle trace (enqueue ->
     admit -> batch-wait -> bucket/pad -> cache lookup -> solve ->
-    resolve) in a low-overhead ring buffer, decomposing the
-    enqueue-to-plan latency EXACTLY into phases;
+    resolve) with each chunk's host leaves and counters, in a ring of
+    numpy columns, decomposing the enqueue-to-plan latency EXACTLY into
+    phases;
   * :mod:`repro.obs.metrics` — :class:`MetricsRegistry` unifying every
     counter source behind one snapshot, with Prometheus text exposition
     (:func:`render_prometheus`) and a strict parser
     (:func:`parse_exposition`) so exports are validated, not assumed;
   * :mod:`repro.obs.journal` — the JSONL event journal (audit log for
     drift / re-plan / session lifecycle events);
-  * :mod:`repro.obs.runtime` — device-vs-host solve attribution via
-    ``block_until_ready`` timing fences inside the jitted kernels, and
-    the optional ``jax.profiler`` capture hook.
+  * :mod:`repro.obs.runtime` — :class:`span`, the one timing helper of
+    the host path (a per-thread chunk record plus a profiler
+    annotation of the same name), the process-wide garbage-collection
+    hook, and the optional ``jax.profiler`` capture hook.
 """
-from repro.obs.hist import LogHistogram, Reservoir, percentiles
+from repro.obs.hist import LogHistogram, percentiles
 from repro.obs.journal import EventJournal, read_jsonl
 from repro.obs.metrics import (Metric, MetricsRegistry, parse_exposition,
                                render_prometheus)
-from repro.obs.runtime import (SolveDelta, profile_capture, record_solve,
-                               solve_delta, solve_totals)
-from repro.obs.spans import PHASES, RequestSpan, SpanRecorder
+from repro.obs.runtime import gc_totals, profile_capture, span
+from repro.obs.spans import (COUNTERS, LEAVES, PHASES, RequestSpan,
+                             SpanRecorder)
 
 __all__ = [
-    "EventJournal", "LogHistogram", "Metric", "MetricsRegistry", "PHASES",
-    "RequestSpan", "Reservoir", "SolveDelta", "SpanRecorder",
-    "parse_exposition", "percentiles", "profile_capture", "read_jsonl",
-    "record_solve", "render_prometheus", "solve_delta", "solve_totals",
+    "COUNTERS", "EventJournal", "LEAVES", "LogHistogram", "Metric",
+    "MetricsRegistry", "PHASES", "RequestSpan", "SpanRecorder",
+    "gc_totals", "parse_exposition", "percentiles", "profile_capture",
+    "read_jsonl", "render_prometheus", "span",
 ]

@@ -1,23 +1,15 @@
-"""Log-spaced mergeable histograms and exact-window reservoirs.
+"""Log-spaced mergeable histograms.
 
-Two bounded-memory representations of a latency distribution, for two
-different jobs:
-
-  * :class:`LogHistogram` — fixed log-spaced buckets whose counts MERGE
-    by addition (associative and commutative, enforced by the property
-    tests), so per-(objective, grid mode, bucket) histograms roll up
-    into one service-wide distribution, and histograms from many service
-    instances roll up into one fleet-wide distribution, without ever
-    shipping raw samples.  Percentiles are geometric interpolation
-    within a bucket: relative error is bounded by the bucket width
-    (``10^(1/per_decade)``, ~26% at the default 10/decade), which is the
-    usual dashboard trade for O(1) memory and mergeability.
-  * :class:`Reservoir` — a raw-sample window keeping the most recent
-    half on overflow, for EXACT percentiles where sample counts are
-    small (per-micro-batch solve latencies).  Halving keeps the window
-    describing recent traffic — what an SLO dashboard wants — and the
-    continuity test pins that halving cannot jump the percentiles of a
-    stationary stream.
+:class:`LogHistogram` keeps fixed log-spaced buckets whose counts MERGE
+by addition (associative and commutative, enforced by the property
+tests), so per-(objective, grid mode, bucket) histograms roll up into
+one service-wide distribution, and histograms from many service
+instances roll up into one fleet-wide distribution, without ever
+shipping raw samples.  Percentiles are geometric interpolation within a
+bucket: relative error is bounded by the bucket width
+(``10^(1/per_decade)``, ~26% at the default 10/decade), which is the
+usual dashboard trade for O(1) memory and mergeability.
+:func:`percentiles` gives exact percentiles of a raw sample list.
 """
 from __future__ import annotations
 
@@ -34,35 +26,6 @@ def percentiles(samples, qs=(50.0, 99.0)) -> Tuple[float, ...]:
         return tuple(0.0 for _ in qs)
     arr = np.asarray(samples, np.float64)
     return tuple(float(np.percentile(arr, q)) for q in qs)
-
-
-class Reservoir:
-    """Bounded raw-sample window: beyond ``max_samples`` the buffer drops
-    its OLDER half, so percentiles describe recent traffic.  Not
-    internally locked — callers that share one across threads hold their
-    own lock (as :class:`repro.serve.stats.StatsRecorder` did when this
-    logic lived there)."""
-
-    def __init__(self, max_samples: int = 65536):
-        if max_samples < 1:
-            raise ValueError(f"max_samples must be >= 1, got {max_samples}")
-        self.max_samples = max_samples
-        self._samples: List[float] = []
-
-    def record(self, x: float) -> None:
-        self._samples.append(float(x))
-        if len(self._samples) > self.max_samples:
-            del self._samples[:len(self._samples) // 2]
-
-    def __len__(self) -> int:
-        return len(self._samples)
-
-    @property
-    def samples(self) -> List[float]:
-        return list(self._samples)
-
-    def percentiles(self, qs=(50.0, 99.0)) -> Tuple[float, ...]:
-        return percentiles(self._samples, qs)
 
 
 class LogHistogram:

@@ -1,19 +1,33 @@
-"""Device-vs-host solve attribution and the profiler capture hook.
+"""Leaf spans of the host path, the chunk records they fill, garbage-
+collection accounting, and the profiler capture hook.
 
-``plan_batch`` wall clock conflates two very different costs: device
-compute (the jitted solve itself) and host work (padding, transfer,
-``np.asarray`` materialisation).  The kernel wrappers in
-``repro.fleet.objective_kernels`` fence the jitted call with
-``jax.block_until_ready`` and report both portions here via
-:func:`record_solve`; the serving layer brackets each micro-batch chunk
-with :func:`solve_delta` to read back exactly the solve time that chunk
-incurred.
+A served chunk costs milliseconds of host work around well under a
+millisecond of device time, and one wall-clock number cannot say where
+it goes.  :class:`span` is the one timing helper of that path: each
+leaf (``serve.wait``, ``planner.dispatch``, ``planner.fetch``, ...) is
+timed once on ``perf_counter`` and
 
-Accumulators are kept BOTH process-global (:func:`solve_totals`, for
-whole-run reporting) and per-thread (what :func:`solve_delta` reads) —
-the test suite runs several services concurrently, and a per-thread
-delta cannot be contaminated by another service's worker solving at the
-same moment.
+  * added to the OPEN RECORD of the current thread, if one is open — a
+    service worker opens one (:func:`open_record`) and takes it at each
+    chunk it writes (:func:`take_record`), so a chunk's record holds the
+    leaves the worker closed since it wrote the previous chunk;
+  * entered as a ``jax.profiler.TraceAnnotation`` of the same name, so
+    the leaf sits on the device trace's clock and names the device's
+    idle gaps.  With no profiler running an annotation costs about a
+    microsecond.
+
+Leaves do not nest on one thread: a trace reader names a device gap by
+the host event that covers most of it, and an enclosing span would
+take the name of every gap inside it.  Records are per thread, so
+several services (or a caller planning on its own thread) never mix
+their leaves.  :func:`count` adds to the open record's counters
+(jitted calls, arrays copied each way, live and unique lanes).
+
+The garbage-collection hook (:func:`install_gc_hook`, reference-counted
+so each running service holds it once) counts collections and pause
+seconds per generation for the whole process, annotates generations 1
+and 2 as ``gc.gen<N>``, and gives each taken record ``gc_s``: the
+process's pause seconds since the record was opened.
 
 :func:`profile_capture` is the opt-in ``jax.profiler`` hook
 (``--profile-dir`` on the serve CLI): a no-op unless a directory is
@@ -21,75 +35,149 @@ given.
 """
 from __future__ import annotations
 
+import gc
 import threading
+import time
 from contextlib import contextmanager
-from dataclasses import dataclass
-from typing import Dict, Iterator, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
+from jax.profiler import TraceAnnotation
 
-_LOCK = threading.Lock()
-_GLOBAL = {"device_s": 0.0, "host_s": 0.0, "calls": 0}
 _TLS = threading.local()
+_perf = time.perf_counter
+
+#: garbage-collector generations counted by the hook
+GC_GENERATIONS = (0, 1, 2)
 
 
-def _tls_totals() -> Dict[str, float]:
-    t = getattr(_TLS, "totals", None)
-    if t is None:
-        t = _TLS.totals = {"device_s": 0.0, "host_s": 0.0, "calls": 0}
-    return t
+class _Record:
+    """What one thread's leaves and counters added since it opened."""
+
+    __slots__ = ("phases", "counts", "gc0")
+
+    def __init__(self):
+        self.phases: Dict[str, float] = {}
+        self.counts: Dict[str, int] = {}
+        self.gc0 = _GC.pause_total
 
 
-def record_solve(device_s: float, host_s: float = 0.0) -> None:
-    """Called by the kernel solve wrappers after every fenced solve.
-    ``device_s`` is the ``block_until_ready``-fenced jitted-call
-    duration; ``host_s`` the host-side materialisation that follows."""
-    device_s = max(0.0, float(device_s))
-    host_s = max(0.0, float(host_s))
-    with _LOCK:
-        _GLOBAL["device_s"] += device_s
-        _GLOBAL["host_s"] += host_s
-        _GLOBAL["calls"] += 1
-    t = _tls_totals()
-    t["device_s"] += device_s
-    t["host_s"] += host_s
-    t["calls"] += 1
+def open_record() -> None:
+    """Start accumulating this thread's leaves (replacing any open
+    record)."""
+    _TLS.record = _Record()
 
 
-def solve_totals() -> Dict[str, float]:
-    """Process-lifetime solve attribution across all threads."""
-    with _LOCK:
-        return dict(_GLOBAL)
+def close_record() -> None:
+    """Stop accumulating this thread's leaves."""
+    _TLS.record = None
 
 
-@dataclass
-class SolveDelta:
-    """Solve time accrued on THIS thread inside a :func:`solve_delta`
-    block.  Live while the block runs, frozen at exit."""
-
-    device_s: float = 0.0
-    host_s: float = 0.0
-    calls: int = 0
-
-    @property
-    def total_s(self) -> float:
-        return self.device_s + self.host_s
+def take_record() -> Optional[Tuple[Dict[str, float], Dict[str, int], float]]:
+    """``(phases, counts, gc_s)`` of this thread's open record, which is
+    replaced by a fresh one; ``None`` when no record is open."""
+    rec = getattr(_TLS, "record", None)
+    if rec is None:
+        return None
+    fresh = _TLS.record = _Record()
+    return rec.phases, rec.counts, max(0.0, fresh.gc0 - rec.gc0)
 
 
-@contextmanager
-def solve_delta() -> Iterator[SolveDelta]:
-    """Measure solve time recorded by the current thread within the
-    block.  Per-thread on purpose: a service worker bracketing its own
-    ``plan_many`` call must not absorb another worker's solves."""
-    t = _tls_totals()
-    before = dict(t)
-    delta = SolveDelta()
-    try:
-        yield delta
-    finally:
-        delta.device_s = t["device_s"] - before["device_s"]
-        delta.host_s = t["host_s"] - before["host_s"]
-        delta.calls = int(t["calls"] - before["calls"])
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to counter ``name`` of this thread's open record."""
+    rec = getattr(_TLS, "record", None)
+    if rec is not None:
+        rec.counts[name] = rec.counts.get(name, 0) + int(n)
+
+
+class span:
+    """``with span("planner.fetch"): ...`` — time a leaf of the host
+    path into this thread's open record and annotate it for the
+    profiler (see the module docstring)."""
+
+    __slots__ = ("name", "_ann", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> "span":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._t0 = _perf()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = _perf() - self._t0
+        self._ann.__exit__(None, None, None)
+        rec = getattr(_TLS, "record", None)
+        if rec is not None:
+            rec.phases[self.name] = rec.phases.get(self.name, 0.0) + dt
+
+
+# ---------------------------------------------------------------------------
+# garbage collection
+# ---------------------------------------------------------------------------
+
+
+class _GcState:
+    """Process-wide collection counts and pauses.  The interpreter runs
+    one collection at a time, so the start/stop pair never interleaves."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.users = 0
+        self.collections = [0] * len(GC_GENERATIONS)
+        self.pause_s = [0.0] * len(GC_GENERATIONS)
+        self.pause_total = 0.0
+        self.t0 = 0.0
+        self.ann: Optional[TraceAnnotation] = None
+
+
+_GC = _GcState()
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        gen = info["generation"]
+        if gen >= 1:
+            _GC.ann = TraceAnnotation(f"gc.gen{gen}")
+            _GC.ann.__enter__()
+        _GC.t0 = _perf()
+        return
+    dt = _perf() - _GC.t0
+    gen = info["generation"]
+    _GC.collections[gen] += 1
+    _GC.pause_s[gen] += dt
+    _GC.pause_total += dt
+    if _GC.ann is not None:
+        _GC.ann.__exit__(None, None, None)
+        _GC.ann = None
+
+
+def install_gc_hook() -> None:
+    """Hold the process's collection hook (installed by the first
+    holder)."""
+    with _GC.lock:
+        _GC.users += 1
+        if _GC.users == 1:
+            gc.callbacks.append(_on_gc)
+
+
+def remove_gc_hook() -> None:
+    """Release the hook (removed with its last holder)."""
+    with _GC.lock:
+        if _GC.users == 0:
+            return
+        _GC.users -= 1
+        if _GC.users == 0 and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+
+
+def gc_totals() -> Dict[str, List[float]]:
+    """Lifetime ``collections`` and ``pause_s`` per generation, counted
+    while the hook was held."""
+    return {"collections": list(_GC.collections),
+            "pause_s": list(_GC.pause_s)}
 
 
 @contextmanager

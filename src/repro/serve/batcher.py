@@ -34,6 +34,13 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Hashable, List, Optional
 
 from repro.core.scenario import Scenario
+from repro.obs import runtime
+
+
+#: longest single wait of an idle worker: a leaf open when a profiler
+#: starts is not in its trace, so an idle wait is cut into pieces this
+#: long, and a trace begun mid-wait sees the rest of it
+IDLE_WAIT_S = 0.02
 
 
 class QueueFull(RuntimeError):
@@ -65,6 +72,8 @@ class PlanRequest:
     #: the resilience layer degrades the request instead of solving it
     #: late — see ``repro.serve.resilience``.
     budget_s: Optional[float] = None
+    #: the batcher flush that took the request (-1 until taken)
+    flush_id: int = -1
 
     def remaining_budget(self, now: Optional[float] = None) \
             -> Optional[float]:
@@ -98,7 +107,14 @@ class MicroBatcher:
     ``plan_group(requests)`` is called on the worker thread with a
     non-empty, (objective, grid-mode)-homogeneous, arrival-ordered list;
     it must resolve every request's future (the batcher resolves them
-    with the exception instead if it raises).
+    with the exception instead if it raises).  Each taken request carries
+    its flush's ``flush_id``.
+
+    The worker holds an open :mod:`repro.obs.runtime` record for its
+    whole life and times two leaves into it: ``serve.wait`` (each
+    ``cv.wait``, for requests or for the flush deadline) and
+    ``serve.take`` (the flush popped and grouped).  ``plan_group`` takes
+    the record at each chunk it writes.
     """
 
     def __init__(self, plan_group: Callable[[List[PlanRequest]], None], *,
@@ -132,6 +148,7 @@ class MicroBatcher:
         self._stopping = False
         self._drain = True
         self.flushes = 0          # micro-batches handed to plan_group
+        self.taken = 0            # flushes taken (the next flush_id)
         self.idle_ticks = 0       # deadline wakes that found nothing to do
         #: per-cause flush counts: "size" (max_batch pending), "deadline"
         #: (oldest request waited out flush_interval), "drain" (shutdown
@@ -193,14 +210,15 @@ class MicroBatcher:
 
     # -- worker -------------------------------------------------------------
 
-    def _take_batch(self) -> Optional[List[PlanRequest]]:
-        """Block until a flush is due; return its requests, or ``None``
-        when stopped and (post-drain) empty."""
+    def _await_flush(self) -> Optional[int]:
+        """Block until a flush is due; return how many requests it takes
+        from the head of the queue, or ``None`` when stopped and
+        (post-drain) empty.  Only the worker pops the queue."""
         with self._cv:
             while True:
                 cause = "drain"
                 while not self._queue and not self._stopping:
-                    self._cv.wait()
+                    self._wait(IDLE_WAIT_S)
                 if not self._queue:
                     return None  # stopping on an empty queue
                 if self._stopping:
@@ -217,7 +235,7 @@ class MicroBatcher:
                         remaining = deadline - time.perf_counter()
                         if remaining <= 0:
                             break
-                        self._cv.wait(timeout=remaining)
+                        self._wait(remaining)
                     if not self._queue:
                         # the deadline wake found nothing to flush (e.g.
                         # a cancel drained the queue mid-wait): count the
@@ -226,25 +244,45 @@ class MicroBatcher:
                         continue
                     cause = ("size" if len(self._queue) >= self.max_batch
                              else "deadline")
-                n = min(self.max_batch, len(self._queue))
                 self.flush_causes[cause] += 1
-                return [self._queue.popleft() for _ in range(n)]
+                return min(self.max_batch, len(self._queue))
+
+    def _wait(self, timeout: float) -> None:
+        """One ``cv.wait`` (lock held) as a ``serve.wait`` leaf."""
+        with runtime.span("serve.wait"):
+            self._cv.wait(timeout)
 
     def _run(self) -> None:
-        while True:
-            batch = self._take_batch()
-            if batch is None:
-                return
-            if self.faults is not None:
-                action = self.faults.draw("queue.stall")
-                if action is not None:
-                    time.sleep(action.duration_s)
-            for group in group_requests(batch,
-                                        key=PlanRequest.group_key):
-                self.flushes += 1
-                try:
-                    self._plan_group(group)
-                except BaseException as e:  # noqa: BLE001 — futures carry it
-                    for req in group:
-                        if not req.future.done():
-                            req.future.set_exception(e)
+        runtime.open_record()
+        try:
+            while self._flush_once():
+                pass
+        finally:
+            runtime.close_record()
+
+    def _flush_once(self) -> bool:
+        """Take one flush and plan its groups; ``False`` once stopped."""
+        n = self._await_flush()
+        if n is None:
+            return False
+        with runtime.span("serve.take"):
+            with self._cv:
+                batch = [self._queue.popleft() for _ in range(n)]
+                flush_id = self.taken
+                self.taken += 1
+            for req in batch:
+                req.flush_id = flush_id
+            groups = group_requests(batch, key=PlanRequest.group_key)
+        if self.faults is not None:
+            action = self.faults.draw("queue.stall")
+            if action is not None:
+                time.sleep(action.duration_s)
+        for group in groups:
+            self.flushes += 1
+            try:
+                self._plan_group(group)
+            except BaseException as e:  # noqa: BLE001 — futures carry it
+                for req in group:
+                    if not req.future.done():
+                        req.future.set_exception(e)
+        return True

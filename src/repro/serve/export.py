@@ -40,6 +40,8 @@ _by_objective_total``
 ``repro_federated_infeasible_rounds_total``       counter    —
 ``repro_federated_round_latency_seconds``         histogram  —
 ``repro_federated_round_time_seconds``            histogram  —
+``repro_process_gc_collections_total``            counter    generation
+``repro_process_gc_pause_seconds_total``          counter    generation
 ================================================  =========  ==========
 
 :func:`register_service_sources` wires a live
@@ -53,8 +55,8 @@ from __future__ import annotations
 from typing import TYPE_CHECKING, Dict, List, Tuple
 
 from repro.fleet.tracing import trace_events
-from repro.obs import (EventJournal, LogHistogram, Metric, MetricsRegistry,
-                       SpanRecorder)
+from repro.obs import (LEAVES, PHASES, EventJournal, LogHistogram, Metric,
+                       MetricsRegistry, SpanRecorder, gc_totals)
 from repro.serve.stats import ServiceStats
 
 if TYPE_CHECKING:  # pragma: no cover - annotation-only import
@@ -171,19 +173,21 @@ def tracing_metrics(events: Dict[Tuple, int] = None) -> List[Metric]:
 
 def span_metrics(spans: SpanRecorder) -> List[Metric]:
     """Lifetime phase totals from the span recorder: the exact
-    decomposition of cumulative enqueue-to-plan latency."""
+    decomposition of cumulative enqueue-to-plan latency, and the
+    worker's host leaves."""
     totals = spans.totals()
     phase = Metric("repro_serve_phase_seconds_total", "counter",
-                   "cumulative request time per lifecycle phase "
-                   "(admit is pre-enqueue, outside the latency SLO)")
-    for name, v in sorted(totals.items()):
-        if name in ("count", "solve_device", "latency"):
-            continue
-        phase.add(v, phase=name)
+                   "lifecycle phases (batch_wait..resolve, admit: request "
+                   "seconds summed over requests; admit is pre-enqueue, "
+                   "outside the latency SLO) and host leaves (serve.*, "
+                   "planner.*: worker seconds summed over chunks)")
+    for name in sorted((*PHASES, "admit", *LEAVES)):
+        phase.add(totals[name], phase=name)
     return [
         phase,
         Metric("repro_serve_solve_device_seconds_total", "counter",
-               "block_until_ready-fenced device portion of solve time")
+               "host seconds waiting on the device after launch "
+               "(planner.device_wait), summed over requests")
         .add(totals["solve_device"]),
         Metric("repro_serve_span_latency_seconds_total", "counter",
                "cumulative enqueue-to-plan latency over all spans")
@@ -195,6 +199,21 @@ def span_metrics(spans: SpanRecorder) -> List[Metric]:
                "lifetime solve share of enqueue-to-plan latency")
         .add(spans.solve_fraction),
     ]
+
+
+def gc_metrics() -> List[Metric]:
+    """The process's garbage collections and pauses per generation,
+    counted while a running service holds the collection hook."""
+    totals = gc_totals()
+    collections = Metric("repro_process_gc_collections_total", "counter",
+                         "garbage collections per generation")
+    pauses = Metric("repro_process_gc_pause_seconds_total", "counter",
+                    "garbage-collection pause seconds per generation")
+    for gen, (n, s) in enumerate(zip(totals["collections"],
+                                     totals["pause_s"])):
+        collections.add(float(n), generation=str(gen))
+        pauses.add(s, generation=str(gen))
+    return [collections, pauses]
 
 
 def federated_metrics(recorder) -> List[Metric]:
@@ -358,6 +377,7 @@ def register_service_sources(registry: MetricsRegistry,
     registry.register_source("tracing", tracing_metrics)
     registry.register_source(
         "spans", lambda: span_metrics(service.spans))
+    registry.register_source("gc", gc_metrics)
     registry.register_source(
         "events", lambda: journal_metrics(service.journal))
     registry.register_source(

@@ -31,8 +31,9 @@ population talks to":
   5. **Observability** — every request leaves a
      :class:`~repro.obs.spans.RequestSpan` decomposing its
      enqueue-to-plan latency exactly into batch-wait / pad / cache-lookup
-     / solve / resolve phases (with the solve's device portion fenced by
-     ``block_until_ready``); latencies aggregate into mergeable
+     / solve / resolve phases, plus its chunk's identifiers, host leaves
+     (``serve.*`` / ``planner.*``, each also a profiler annotation) and
+     counters; latencies aggregate into mergeable
      log-histograms per (objective, grid mode, bucket); drift and
      session lifecycle events land in a JSONL-exportable audit journal;
      and ``service.metrics`` — a :class:`~repro.obs.metrics\
@@ -53,6 +54,8 @@ from concurrent.futures import Future
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Sequence, Tuple
 
+import numpy as np
+
 from repro.chaos import FaultPlan, parse_chaos_spec
 from repro.core.bounds import BoundConstants
 from repro.core.scenario import Scenario
@@ -62,7 +65,7 @@ from repro.fleet import GRID_MODES, MC_IMPLS, FleetPlanner, PlanCache
 from repro.fleet.objective_kernels import pow2ceil
 from repro.fleet.tracing import trace_delta
 from repro.obs import (EventJournal, MetricsRegistry, RequestSpan,
-                       SpanRecorder, solve_delta)
+                       SpanRecorder, runtime)
 from repro.serve import export
 from repro.serve.batcher import MicroBatcher, PlanRequest, QueueFull
 from repro.serve.catalogue import (ALL_MODELS, FEDERATED_KIND,
@@ -132,8 +135,9 @@ class ServiceConfig:
     #: round requests inside the largest bucket hit compiled code only.
     population_buckets: Tuple[int, ...] = ()
     #: span ring capacity (lifetime phase TOTALS are kept regardless;
-    #: the ring holds the most recent complete traces)
-    span_capacity: int = 8192
+    #: the ring holds the most recent complete traces): a minute at
+    #: 2,200 plans/s, in numpy columns, with no object per request
+    span_capacity: int = 131072
     #: event-journal ring capacity (per-kind counts are lifetime)
     journal_capacity: int = 4096
     #: when set, every journal event is also appended to this JSONL file
@@ -319,15 +323,24 @@ class PlanningService:
         self.warmed = False
         self.warmup_traces = 0
         self.warmup_seconds = 0.0
+        self._gc_hooked = False
 
     # -- lifecycle ----------------------------------------------------------
 
     def start(self) -> "PlanningService":
+        """Start the worker; a running service holds the process's
+        garbage-collection hook (``repro.obs.runtime``)."""
         self.batcher.start()
+        if not self._gc_hooked:
+            runtime.install_gc_hook()
+            self._gc_hooked = True
         return self
 
     def stop(self, drain: bool = True) -> None:
         self.batcher.stop(drain=drain)
+        if self._gc_hooked:
+            runtime.remove_gc_hook()
+            self._gc_hooked = False
 
     def __enter__(self) -> "PlanningService":
         return self.start()
@@ -521,7 +534,7 @@ class PlanningService:
         self.recorder.count("round_requests")
         record = self.cache.get_by_key(key, label=FEDERATED_KIND)
         if record is None:
-            with trace_delta() as traces, solve_delta():
+            with trace_delta() as traces:
                 plan = self.round_planner.plan_round(
                     population, self.consts, deadline=deadline,
                     pad_to=bucket)
@@ -586,16 +599,19 @@ class PlanningService:
         # after the cooldown, making this solve the probe).  With no
         # budgets, no faults, and a closed breaker this adds nothing to
         # the path: same plan_many, bitwise-identical records.
-        degraded = []  # (request, reason) pairs for the ladder
-        solve_reqs, over_budget = res.split_over_budget(requests, oid, mode)
-        degraded.extend((r, "budget") for r in over_budget)
-        if solve_reqs and not res.breaker(oid, mode).allow():
-            degraded.extend((r, "breaker_open") for r in solve_reqs)
-            solve_reqs = []
+        with runtime.span("serve.pad"):
+            degraded = []  # (request, reason) pairs for the ladder
+            solve_reqs, over_budget = res.split_over_budget(requests, oid,
+                                                            mode)
+            degraded.extend((r, "budget") for r in over_budget)
+            if solve_reqs and not res.breaker(oid, mode).allow():
+                degraded.extend((r, "breaker_open") for r in solve_reqs)
+                solve_reqs = []
+            buckets = (self._chunk_buckets(len(solve_reqs))
+                       if solve_reqs else ())
 
         lo = 0
-        for bucket in (self._chunk_buckets(len(solve_reqs))
-                       if solve_reqs else ()):
+        for bucket in buckets:
             chunk = solve_reqs[lo:lo + bucket]
             lo += len(chunk)
             try:
@@ -609,7 +625,12 @@ class PlanningService:
                      objective) -> None:
         """Solve one padded chunk (under retry/fault injection), resolve
         its futures, and record its spans.  Raises once retries are
-        exhausted — the caller sends the chunk down the ladder."""
+        exhausted — the caller sends the chunk down the ladder.
+
+        The chunk's record (``repro.obs.runtime``) holds the leaves the
+        worker closed since it wrote the previous chunk, this chunk's
+        ``serve.resolve`` last; its ``serve.record`` (the bookkeeping
+        below, the span write included) lands in the next chunk's."""
         res = self.resilience
         t_chunk = time.perf_counter()
         timings: Dict[str, float] = {}
@@ -621,43 +642,48 @@ class PlanningService:
                 cache=self.cache, pad_to=bucket, objective=objective,
                 grid_mode=mode, timings=timings)
 
-        with trace_delta() as traces, solve_delta() as solve:
+        with trace_delta() as traces:
             records = res.run_attempts(oid, mode, _attempt)
         t_planned = time.perf_counter()
-        self.recorder.record_bucket(oid, mode, bucket,
-                                    requests=len(chunk), batches=1,
-                                    compiles=traces.total)
-        self.recorder.count("batches")
-        self.recorder.count("planned", len(chunk))
-        if traces.total and self.warmed:
-            self.recorder.count("post_warmup_traces", traces.total)
-        for request, record in zip(chunk, records):
-            if request.session_id is not None:
-                self._deliver_to_session(request.session_id, record)
-            request.future.set_result(record)
+        with runtime.span("serve.resolve"):
+            for request, record in zip(chunk, records):
+                if request.session_id is not None:
+                    self._deliver_to_session(request.session_id, record)
+                request.future.set_result(record)
         t_end = time.perf_counter()
 
-        cache_s = timings.get("cache_lookup_s", 0.0)
-        solve_s = timings.get("solve_s", 0.0)
-        res.estimator.observe(oid, mode, solve_s)
-        if records:
-            res.note_last_good(oid, mode, records[-1])
-        pad_s = max(0.0, (t_planned - t_chunk) - cache_s - solve_s)
-        resolve_s = max(0.0, (t_end - t_chunk)
-                        - (pad_s + cache_s + solve_s))
-        device_s = min(solve.device_s, solve_s)
-        key = (oid, mode, bucket)
-        for request in chunk:
-            latency = t_end - request.enqueue_t
-            self.recorder.record_latency(latency, key=key)
-            self.spans.record(RequestSpan(
+        with runtime.span("serve.record"):
+            taken = runtime.take_record() or ({}, {}, 0.0)
+            self.recorder.record_bucket(oid, mode, bucket,
+                                        requests=len(chunk), batches=1,
+                                        compiles=traces.total)
+            self.recorder.count("batches")
+            self.recorder.count("planned", len(chunk))
+            if traces.total and self.warmed:
+                self.recorder.count("post_warmup_traces", traces.total)
+            cache_s = timings.get("cache_lookup_s", 0.0)
+            solve_s = timings.get("solve_s", 0.0)
+            res.estimator.observe(oid, mode, solve_s)
+            if records:
+                res.note_last_good(oid, mode, records[-1])
+            pad_s = max(0.0, (t_planned - t_chunk) - cache_s - solve_s)
+            resolve_s = max(0.0, (t_end - t_chunk)
+                            - (pad_s + cache_s + solve_s))
+            enqueue_t = np.fromiter((r.enqueue_t for r in chunk),
+                                    np.float64, len(chunk))
+            key = (oid, mode, bucket)
+            for latency in (t_end - enqueue_t).tolist():
+                self.recorder.record_latency(latency, key=key)
+            phases, counts, gc_s = taken
+            self.spans.record_chunk(
                 objective=oid, grid_mode=mode, bucket=bucket,
-                enqueue_t=request.enqueue_t,
-                admit_s=request.admit_s,
-                batch_wait_s=t_chunk - request.enqueue_t,
-                pad_s=pad_s, cache_lookup_s=cache_s,
-                solve_s=solve_s, solve_device_s=device_s,
-                resolve_s=resolve_s, latency_s=latency))
+                enqueue_t=enqueue_t,
+                admit_s=np.fromiter((r.admit_s for r in chunk),
+                                    np.float64, len(chunk)),
+                t_start=t_chunk, t_end=t_end, pad_s=pad_s,
+                cache_lookup_s=cache_s, solve_s=solve_s,
+                resolve_s=resolve_s, flush_id=chunk[0].flush_id,
+                phases=phases, counts=counts, gc_s=gc_s)
 
     def _finish_degraded(self, request, record, oid: str, mode: str,
                          t_start: float) -> None:
@@ -678,7 +704,7 @@ class PlanningService:
             batch_wait_s=batch_wait, pad_s=0.0, cache_lookup_s=0.0,
             solve_s=0.0, solve_device_s=0.0,
             resolve_s=max(0.0, latency - batch_wait),
-            latency_s=latency))
+            latency_s=latency, flush_id=request.flush_id))
 
     def _degrade_requests(self, oid: str, mode: str, objective,
                           pairs) -> None:

@@ -1,6 +1,6 @@
 """Observability layer: log-spaced mergeable histograms, request spans,
-the event journal, Prometheus render/parse round-trips, solve/trace
-delta brackets, and the StatsRecorder throughput-baseline fix."""
+the event journal, Prometheus render/parse round-trips, trace delta
+brackets, and the StatsRecorder throughput-baseline fix."""
 import json
 import math
 import threading
@@ -10,9 +10,8 @@ import pytest
 
 from repro.fleet.tracing import record_trace, trace_delta
 from repro.obs import (EventJournal, LogHistogram, Metric, MetricsRegistry,
-                       RequestSpan, Reservoir, SpanRecorder, parse_exposition,
-                       percentiles, read_jsonl, record_solve, solve_delta,
-                       render_prometheus)
+                       RequestSpan, SpanRecorder, parse_exposition,
+                       percentiles, read_jsonl, render_prometheus)
 from repro.serve.stats import StatsRecorder
 
 
@@ -110,33 +109,12 @@ def test_histogram_dict_round_trip():
 
 
 # ---------------------------------------------------------------------------
-# Reservoir
+# percentiles
 # ---------------------------------------------------------------------------
-
-def test_reservoir_halving_keeps_percentiles_continuous():
-    rng = np.random.default_rng(3)
-    r = Reservoir(max_samples=1000)
-    stream = rng.normal(100.0, 10.0, size=1000)
-    for s in stream:
-        r.record(float(s))
-    p50_before, p99_before = r.percentiles()
-    r.record(float(rng.normal(100.0, 10.0)))   # trips the halving
-    assert len(r) == 501
-    p50_after, p99_after = r.percentiles()
-    # stationary stream: dropping the older half cannot jump percentiles
-    assert p50_after == pytest.approx(p50_before, rel=0.05)
-    assert p99_after == pytest.approx(p99_before, rel=0.05)
-
 
 def test_reservoir_and_percentiles_edge_cases():
     assert percentiles([]) == (0.0, 0.0)
     assert percentiles([2.0], qs=(50.0,)) == (2.0,)
-    with pytest.raises(ValueError):
-        Reservoir(max_samples=0)
-    r = Reservoir(max_samples=4)
-    for i in range(6):
-        r.record(i)
-    assert r.samples == [2.0, 3.0, 4.0, 5.0]   # recent half survives
 
 
 # ---------------------------------------------------------------------------
@@ -384,35 +362,8 @@ def test_registry_write_textfile_is_parseable(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# solve_delta / trace_delta brackets
+# trace_delta brackets
 # ---------------------------------------------------------------------------
-
-def test_solve_delta_is_per_thread():
-    noise_done = threading.Event()
-
-    def other_thread():
-        record_solve(100.0, 50.0)   # must NOT leak into our delta
-        noise_done.set()
-
-    with solve_delta() as delta:
-        t = threading.Thread(target=other_thread)
-        t.start()
-        t.join()
-        assert noise_done.wait(5.0)
-        record_solve(0.25, 0.05)
-        record_solve(0.75)
-    assert delta.calls == 2
-    assert delta.device_s == pytest.approx(1.0)
-    assert delta.host_s == pytest.approx(0.05)
-    assert delta.total_s == pytest.approx(1.05)
-
-
-def test_record_solve_clamps_negative_durations():
-    with solve_delta() as delta:
-        record_solve(-1.0, -2.0)
-    assert delta.calls == 1
-    assert delta.device_s == 0.0 and delta.host_s == 0.0
-
 
 def test_trace_delta_counts_only_inner_traces():
     record_trace(("test_obs_outer", 1))

@@ -11,6 +11,7 @@ import pytest
 from repro.core import (BoundConstants, ErasureLink, GilbertElliottLink,
                         Scenario)
 from repro.fleet import FleetPlanner, PlanCache
+from repro.obs import LEAVES, PHASES
 from repro.serve import (AdmissionDecision, MicroBatcher, PlanRequest,
                          PlanningService, ServiceConfig, group_requests,
                          policy_spec, register_policy, registered_policies,
@@ -483,12 +484,22 @@ def test_service_metrics_round_trip_all_counters(warm_service):
     # exported phase totals re-partition the exported latency total
     assert snap["repro_serve_spans_recorded_total"][()] \
         == snap["repro_serve_latency_seconds_count"][()]
-    phase_total = sum(
-        v for labels, v in snap["repro_serve_phase_seconds_total"].items()
-        if dict(labels)["phase"] != "admit")
+    phases = {dict(labels)["phase"]: v for labels, v
+              in snap["repro_serve_phase_seconds_total"].items()}
+    phase_total = sum(phases[p] for p in PHASES)
     assert phase_total == pytest.approx(
         snap["repro_serve_span_latency_seconds_total"][()], rel=1e-6)
     assert snap["repro_serve_solve_device_seconds_total"][()] > 0.0
+    # the worker's host leaves are labels of the same family, and the
+    # collection counters come per generation
+    assert set(LEAVES) <= set(phases)
+    for leaf in ("serve.wait", "planner.dispatch", "planner.device_wait",
+                 "planner.fetch", "serve.resolve"):
+        assert phases[leaf] > 0.0, leaf
+    for family in ("repro_process_gc_collections_total",
+                   "repro_process_gc_pause_seconds_total"):
+        assert {dict(labels)["generation"] for labels in snap[family]} \
+            == {"0", "1", "2"}
     assert 0.0 < snap["repro_serve_solve_fraction"][()] <= 1.0
     # the zero-trace SLO series a scrape would alert on
     assert snap["repro_serve_post_warmup_traces_total"][()] == 0

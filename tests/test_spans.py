@@ -1,0 +1,298 @@
+"""Leaf spans of the host path: per-thread chunk records, the span ring's
+columns and identifiers, the garbage-collection hook, and the program
+spans a profiler trace of a served stream shows."""
+import gc
+import glob
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from repro.core import BoundConstants
+from repro.fleet import FleetPlanner, PlanCache
+from repro.obs import LEAVES, RequestSpan, SpanRecorder, runtime
+from repro.serve import (MicroBatcher, PlanningService, PlanRequest,
+                         ServiceConfig, synth_requests)
+
+CONSTS = BoundConstants(L=1.908, c=0.061, M=1.0, M_G=1.0, D=1.0, alpha=1e-4)
+SMALL = dict(grid_size=16, batch_buckets=(4, 8), flush_interval=0.01,
+             objective_ids=("corollary1", "markov_arq"), n_max=512)
+#: planner leaves timed inside ``plan_many``'s solve interval
+SOLVE_LEAVES = ("planner.build", "planner.dispatch", "planner.device_wait",
+                "planner.fetch", "planner.refine_host", "planner.records")
+
+
+@pytest.fixture(scope="module")
+def service():
+    svc = PlanningService(ServiceConfig(**SMALL), consts=CONSTS)
+    svc.warmup()
+    svc.start()
+    yield svc
+    svc.stop()
+
+
+def _serve(svc, n, seed, mode="refine"):
+    reqs = synth_requests(n, seed=seed, dup_frac=0.0, n_classes=n,
+                          models=("erasure", "gilbert_elliott"), n_max=512)
+    futures = [svc.submit(sc, objective="corollary1", grid_mode=mode)
+               for sc in reqs]
+    for f in futures:
+        f.result(timeout=60)
+
+
+def test_phase_accumulation_is_per_thread():
+    seen = {}
+
+    def worker(name, n):
+        runtime.open_record()
+        for _ in range(n):
+            with runtime.span(name):
+                pass
+        runtime.count("dispatches", n)
+        seen[name] = runtime.take_record()
+        runtime.close_record()
+
+    runtime.open_record()
+    try:
+        with runtime.span("planner.fetch"):
+            threads = [threading.Thread(target=worker, args=(leaf, k + 2))
+                       for k, leaf in enumerate(("serve.wait",
+                                                 "planner.dispatch"))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+        phases, counts, _ = runtime.take_record()
+    finally:
+        runtime.close_record()
+    # each thread saw only its own leaves and counters
+    assert set(phases) == {"planner.fetch"} and counts == {}
+    assert set(seen["serve.wait"][0]) == {"serve.wait"}
+    assert seen["serve.wait"][1] == {"dispatches": 2}
+    assert set(seen["planner.dispatch"][0]) == {"planner.dispatch"}
+    assert seen["planner.dispatch"][1] == {"dispatches": 3}
+    # taking a record opens a fresh one; with none open, spans add nothing
+    runtime.open_record()
+    runtime.take_record()
+    assert runtime.take_record()[:2] == ({}, {})
+    runtime.close_record()
+    with runtime.span("serve.take"):
+        runtime.count("dispatches")
+    assert runtime.take_record() is None
+
+
+def test_planner_leaves_within_solve_and_phases_sum_to_latency(service):
+    _serve(service, 12, seed=60)
+    spans = [s for s in service.spans.snapshot() if s.bucket > 0]
+    assert spans
+    for s in spans:
+        # the five request phases still partition the latency exactly
+        assert abs(s.phase_sum - s.latency_s) <= 1e-6, s
+        leaves = s.leaves()
+        solve_leaves = sum(leaves[name] for name in SOLVE_LEAVES)
+        assert solve_leaves <= s.solve_s + 1e-9, s
+        assert leaves["planner.cache_lookup"] <= s.cache_lookup_s + 1e-9
+        assert s.solve_device_s == pytest.approx(
+            min(leaves["planner.device_wait"], s.solve_s))
+        assert s.dispatches >= 1 and s.h2d_arrays >= s.dispatches
+        assert s.d2h_arrays >= s.dispatches
+        assert 1 <= s.lanes_unique <= s.lanes_live <= s.bucket
+    # a refined solve on a grid wide enough to refine: a coarse and a
+    # fine pass with host work between them, all inside solve_s
+    reqs = synth_requests(6, seed=63, dup_frac=0.0, n_classes=6,
+                          models=("erasure",), n_max=4096)
+    timings = {}
+    runtime.open_record()
+    try:
+        FleetPlanner(grid_size=128).plan_many(
+            reqs, CONSTS, cache=PlanCache(maxsize=64), pad_to=8,
+            grid_mode="refine", timings=timings)
+        phases, counts, _ = runtime.take_record()
+    finally:
+        runtime.close_record()
+    assert counts["dispatches"] == 2
+    assert counts["lanes_live"] == counts["lanes_unique"] == 6
+    assert phases["planner.refine_host"] > 0.0
+    assert sum(phases[name] for name in SOLVE_LEAVES) <= timings["solve_s"]
+
+
+def test_requests_of_one_chunk_share_chunk_id(service):
+    before = service.spans.recorded
+    batches = service.stats().counters["batches"]
+    _serve(service, 16, seed=61, mode="dense")
+    spans = service.spans.snapshot()[-(service.spans.recorded - before):]
+    by_chunk = {}
+    for s in spans:
+        by_chunk.setdefault(s.chunk_id, []).append(s)
+    assert len(by_chunk) >= 2 and min(by_chunk) >= 0
+    for members in by_chunk.values():
+        # chunk-shared fields agree within a chunk
+        assert len({(m.flush_id, m.solve_s, m.pad_s, m.dispatches,
+                     m.bucket) for m in members}) == 1
+        assert members[0].lanes_live == len(members)
+    # distinct chunks carry distinct identifiers, in recording order
+    ids = [s.chunk_id for s in spans]
+    assert ids == sorted(ids)
+    assert len(by_chunk) == service.stats().counters["batches"] - batches
+
+
+def test_span_ring_columns_and_identifiers():
+    rec = SpanRecorder(capacity=6)
+    a = rec.record_chunk(objective="corollary1", grid_mode="dense",
+                         bucket=4, enqueue_t=[0.0, 0.5, 1.0], admit_s=1e-5,
+                         t_start=2.0, t_end=3.0, pad_s=0.1, solve_s=0.5,
+                         flush_id=7, phases={"planner.dispatch": 0.2,
+                                             "planner.device_wait": 0.1},
+                         counts={"dispatches": 1, "lanes_live": 3},
+                         gc_s=0.01)
+    b = rec.record_chunk(objective="markov_arq", grid_mode="refine",
+                         bucket=4, enqueue_t=[2.5, 2.6], admit_s=[0.0, 0.0],
+                         t_start=3.0, t_end=3.5, flush_id=8)
+    assert rec.record_chunk(objective="x", grid_mode="dense", bucket=4,
+                            enqueue_t=[], admit_s=0.0, t_start=0.0,
+                            t_end=0.0) == -1
+    assert (a, b) == (0, 1)
+    spans = rec.snapshot()
+    assert [s.chunk_id for s in spans] == [0, 0, 0, 1, 1]
+    assert [s.flush_id for s in spans] == [7, 7, 7, 8, 8]
+    first = spans[0]
+    assert first.batch_wait_s == pytest.approx(2.0)
+    assert first.latency_s == pytest.approx(3.0)
+    assert first.resolve_s == pytest.approx(0.4)   # the remainder
+    assert first.planner_dispatch_s == pytest.approx(0.2)
+    assert first.solve_device_s == pytest.approx(0.1)
+    assert (first.dispatches, first.lanes_live, first.gc_s) == (1, 3, 0.01)
+    assert spans[3].objective == "markov_arq"
+    assert all(abs(s.phase_sum - s.latency_s) < 1e-12 for s in spans)
+    # wrapping evicts the oldest requests; no request outlives its chunk
+    for i in range(4):
+        rec.record_chunk(objective="corollary1", grid_mode="dense",
+                         bucket=4, enqueue_t=[10.0 + i], admit_s=0.0,
+                         t_start=11.0 + i, t_end=12.0 + i)
+    spans = rec.snapshot()
+    assert len(spans) == 6 and rec.recorded == 9
+    assert [s.chunk_id for s in spans] == [1, 1, 2, 3, 4, 5]
+    totals = rec.totals()
+    assert totals["chunks"] == 6 and totals["count"] == 9
+    assert totals["planner.dispatch"] == pytest.approx(0.2)
+
+
+def test_recording_100k_spans_keeps_no_python_objects():
+    rec = SpanRecorder()
+    assert rec.capacity >= 131072
+    phases = {name: 1e-4 for name in LEAVES}
+    counts = {"dispatches": 2, "lanes_live": 64, "lanes_unique": 64}
+    enq = np.arange(64, dtype=np.float64)
+    admit = np.zeros(64)
+    gc.collect()
+    before = len(gc.get_objects())
+    for c in range(1563):                      # 100,032 requests
+        rec.record_chunk(objective="corollary1", grid_mode="dense",
+                         bucket=64, enqueue_t=enq + c, admit_s=admit,
+                         t_start=c + 64.0, t_end=c + 64.01, pad_s=1e-4,
+                         solve_s=1e-2, flush_id=c, phases=phases,
+                         counts=counts)
+    gc.collect()
+    assert len(gc.get_objects()) - before < 1000
+    assert rec.recorded == 100032 and len(rec) == 100032
+    spans = rec.snapshot()
+    assert len(spans) == 100032 and spans[-1].chunk_id == 1562
+    assert isinstance(spans[0], RequestSpan) and spans[0].lanes_live == 64
+
+
+def test_batcher_stamps_flush_ids_and_times_its_leaves():
+    taken = []
+
+    def plan_group(reqs):
+        taken.append(([r.flush_id for r in reqs], runtime.take_record()))
+        for r in reqs:
+            r.future.set_result(r.scenario)
+
+    b = MicroBatcher(plan_group, max_batch=3, flush_interval=0.005)
+    b.start()
+    try:
+        for batch in ((0, 1, 2), (3,)):
+            futs = [b.submit(PlanRequest(scenario=i)) for i in batch]
+            for f in futs:
+                f.result(timeout=5.0)
+    finally:
+        b.stop()
+    ids = [i for flush, _ in taken for i in flush]
+    assert ids == sorted(ids) and ids[0] == 0 and len(set(ids)) == len(taken)
+    assert b.taken == len(taken)
+    for _, (phases, _, _) in taken:
+        assert {"serve.wait", "serve.take"} <= set(phases)
+
+
+def _host_lines(directory):
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(f"{directory}/**/*.xplane.pb",
+                            recursive=True))[-1]
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device"):
+            continue
+        for line in plane.lines:
+            lines.append([(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                          for e in line.events])
+    return lines
+
+
+def _profile(directory, body):
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(directory), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_lines(directory)
+
+
+def test_profiled_stream_names_program_spans_on_the_host(service, tmp_path):
+    lines = _profile(tmp_path, lambda: _serve(service, 12, seed=62))
+    names = {name for line in lines for name, _, _ in line}
+    assert {"serve.wait", "planner.dispatch", "planner.fetch",
+            "serve.resolve"} <= names
+    # the worker's program spans are leaves: none overlaps another
+    worker = [line for line in lines
+              if any(name == "serve.resolve" for name, _, _ in line)]
+    assert len(worker) == 1
+    leaves = sorted((a, b, name) for name, a, b in worker[0]
+                    if name.startswith(("serve.", "planner.")))
+    assert len(leaves) >= 10
+    for (_, end, prev), (start, _, name) in zip(leaves, leaves[1:]):
+        assert start >= end, (prev, name)
+
+
+def test_gc_collect_is_annotated_and_counted(tmp_path):
+    runtime.install_gc_hook()
+    try:
+        before = runtime.gc_totals()
+        runtime.open_record()
+        lines = _profile(tmp_path, lambda: gc.collect())
+        _, _, gc_s = runtime.take_record()
+        runtime.close_record()
+        after = runtime.gc_totals()
+    finally:
+        runtime.remove_gc_hook()
+    assert "gc.gen2" in {name for line in lines for name, _, _ in line}
+    assert after["collections"][2] > before["collections"][2]
+    assert after["pause_s"][2] > before["pause_s"][2]
+    assert gc_s > 0.0
+
+
+def test_idle_worker_shows_in_a_trace_begun_mid_wait(tmp_path):
+    b = MicroBatcher(lambda reqs: None, max_batch=4, flush_interval=0.005)
+    b.start()
+    try:
+        time.sleep(0.05)        # the worker is already waiting
+        lines = _profile(tmp_path, lambda: time.sleep(0.2))
+    finally:
+        b.stop()
+    waits = [(a, z) for line in lines for name, a, z in line
+             if name == "serve.wait"]
+    # the idle wait is cut into pieces, so the trace holds most of it
+    assert sum(z - a for a, z in waits) >= 0.5 * 0.2e9

@@ -53,6 +53,7 @@ retrace rather than stale-dispatch.
 """
 from __future__ import annotations
 
+import math
 from functools import lru_cache, partial
 from typing import Callable, Dict
 
@@ -125,12 +126,52 @@ def _count_in(n_in: int) -> None:
 
 def _fetch(out: dict) -> dict:
     """Wait on a jitted call's results and copy them back to host
-    arrays, as two leaves."""
+    arrays, as two leaves: one transfer per array.
+
+    The Monte-Carlo solve and the federated round fetch this way: their
+    outputs differ from the grid solves' and no benchmark cell measures
+    them, so they keep the plain per-array path.  The grid solves pack
+    their outputs into one buffer (:func:`_fetch_packed`)."""
     with span("planner.device_wait"):
         jax.block_until_ready(out)
     with span("planner.fetch"):
         res = {k: np.asarray(v) for k, v in out.items()}
         count("d2h_arrays", len(res))
+    return res
+
+
+def _pack(out: dict):
+    """Every ``(S, ...)`` output as float64 columns of one ``(S, K)``
+    buffer, and the static layout ``((key, start, stop, dtype, trailing
+    shape), ...)`` that :func:`_fetch_packed` reads it back with.
+
+    Called while tracing.  Integer and bool outputs round-trip exactly:
+    served block sizes (at most the service's ``n_max``) and indices
+    (below the grid width) are below 2**24, exact even in the TPU's
+    emulated float64.  Concatenating along axis 1 keeps a
+    scenario-sharded batch sharded."""
+    S = next(iter(out.values())).shape[0]
+    cols, layout, start = [], [], 0
+    for key, v in out.items():
+        trailing = tuple(v.shape[1:])
+        width = math.prod(trailing)
+        cols.append(v.reshape(S, width).astype(jnp.float64))
+        layout.append((key, start, start + width, np.dtype(v.dtype),
+                       trailing))
+        start += width
+    return jnp.concatenate(cols, axis=1), tuple(layout)
+
+
+def _fetch_packed(buf, layout) -> dict:
+    """:func:`_fetch` for a packed solve: wait, then ONE copy back, cut
+    into the same dict of host arrays (keys, dtypes, shapes, values)."""
+    with span("planner.device_wait"):
+        buf.block_until_ready()
+    with span("planner.fetch"):
+        host = np.asarray(buf)
+        count("d2h_arrays")
+        res = {key: host[:, a:b].astype(dtype).reshape((-1,) + trailing)
+               for key, a, b, dtype, trailing in layout}
     return res
 
 
@@ -231,6 +272,14 @@ def _corollary1_values(g, N, T, n_o_eff, tau_p, sigma, e0, contraction):
                                 sigma=sigma, e0=e0, contraction=contraction)
 
 
+def _layout_key(rates, grid, width=None) -> tuple:
+    """What fixes a grid solve's packed layout: the rate count, the
+    grid's trailing shape and dtype, and the fine pass's window width.
+    The same key from the traced arguments and from the host's."""
+    return (rates.shape[1], tuple(grid.shape[1:]), np.dtype(grid.dtype),
+            width)
+
+
 def _build_grid_solve(branches, value_fn, exact_arq: bool):
     """Jit a grid-objective solve closed over a link-kernel branch table.
 
@@ -239,22 +288,39 @@ def _build_grid_solve(branches, value_fn, exact_arq: bool):
     ARQ inflation for the exact Markov-reward block time on
     non-degenerate Gilbert-Elliott rows.
 
-    Returns ``(grid_solve, grid_solve_fine)``: the single-pass solve
-    over a ``(S, G)`` / per-rate ``(S, R, G)`` grid (the dense solve and
-    the coarse pass), and the FUSED fine pass of the coarse->fine solve,
-    which builds the per-rate bracket+tail windows ON DEVICE from
+    Returns ``(grid_solve, grid_solve_fine, layout)``: the single-pass
+    solve over a ``(S, G)`` / per-rate ``(S, R, G)`` grid (the dense
+    solve and the coarse pass), the FUSED fine pass of the coarse->fine
+    solve, which builds the per-rate bracket+tail windows ON DEVICE from
     ``(centers, tail_start)`` — mirroring
     :func:`repro.core.planner.refine_window_bounds` op-for-op — so the
     serving hot path never materialises or transfers ``(S, R, W)``
-    window arrays from the host.  The functions' names are what the
-    profiler shows: ``PjitFunction(grid_solve)`` on the host and the
-    ``jit_grid_solve`` module on the device.
-    """
+    window arrays from the host, and ``layout(rates, grid, width=None)``.
 
-    def grid_solve(N, T, union_no, tau_p, rates, rate_mask, grid,
+    Both jitted functions end in :func:`_pack`: they return ONE float64
+    ``(S, K)`` device buffer holding every output of
+    :func:`_reduce_joint_argmin` as column blocks in its key order —
+    ``n_c``, ``rate``, ``bound_value``, ``p_err``, ``n_o_eff``,
+    ``full_transfer`` one column each, then ``bound_grid`` (G or W
+    columns), ``gi_per_rate`` and ``val_per_rate`` (R each) and, on a
+    per-rate grid, ``sel_grid`` — so the host pays one transfer per solve
+    instead of nine.  The layout is recorded while tracing, keyed by
+    :func:`_layout_key`; ``layout`` looks it up for a call's host
+    arguments (its trace has run by the time the call returns).  The
+    functions' names are what the profiler shows:
+    ``PjitFunction(grid_solve)`` on the host and the ``jit_grid_solve``
+    module on the device.
+    """
+    layouts = {}
+
+    def packed(out, rates, grid, width=None):
+        buf, layouts[_layout_key(rates, grid, width)] = _pack(out)
+        return buf
+
+    def solve_grid(N, T, union_no, tau_p, rates, rate_mask, grid,
                    link_model_id, link_params, sigma, e0, contraction):
-        # runs once per TRACE (both the dense jit and grid_solve_fine
-        # funnel through this body) — the serving layer's retrace audit
+        # runs once per TRACE (both jitted solves funnel through this
+        # body) — the serving layer's retrace audit
         record_trace(("grid", int(exact_arq)) + tuple(grid.shape))
         rate = rates[:, :, None]                                   # (S, R, 1)
         # (S, G) shared grid broadcasts over rates; a (S, R, G) window
@@ -282,10 +348,17 @@ def _build_grid_solve(branches, value_fn, exact_arq: bool):
         return _reduce_joint_argmin(vals, n_o_eff, p, N, T, rates,
                                     rate_mask, grid)
 
+    @jax.jit
+    def grid_solve(N, T, union_no, tau_p, rates, rate_mask, grid,
+                   link_model_id, link_params, sigma, e0, contraction):
+        return packed(solve_grid(N, T, union_no, tau_p, rates, rate_mask,
+                                 grid, link_model_id, link_params, sigma,
+                                 e0, contraction), rates, grid)
+
     @partial(jax.jit, static_argnames=("stride", "width"))
     def grid_solve_fine(N, T, union_no, tau_p, rates, rate_mask, grid,
-                       link_model_id, link_params, sigma, e0, contraction,
-                       centers, tail_start, *, stride, width):
+                        link_model_id, link_params, sigma, e0, contraction,
+                        centers, tail_start, *, stride, width):
         S, G = grid.shape
         # jnp mirror of repro.core.planner.refine_window_bounds (+ the
         # refine_grid padding rule): integer ops, so both paths agree
@@ -305,11 +378,15 @@ def _build_grid_solve(branches, value_fn, exact_arq: bool):
         win = win + (t2 - lo - len1)[..., None] * (j >= len1[..., None])
         win = jnp.minimum(win, pad[..., None])                     # (S, R, W)
         win_grid = grid[jnp.arange(S)[:, None, None], win]
-        return grid_solve(N, T, union_no, tau_p, rates, rate_mask, win_grid,
-                          link_model_id, link_params, sigma, e0,
-                          contraction)
+        return packed(solve_grid(N, T, union_no, tau_p, rates, rate_mask,
+                                 win_grid, link_model_id, link_params,
+                                 sigma, e0, contraction),
+                      rates, grid, width)
 
-    return jax.jit(grid_solve), grid_solve_fine
+    def layout(rates, grid, width=None):
+        return layouts[_layout_key(rates, grid, width)]
+
+    return grid_solve, grid_solve_fine, layout
 
 
 @lru_cache(maxsize=16)
@@ -334,6 +411,12 @@ def grid_objective_builder(value_fn, exact_arq: bool = False) -> Callable:
     coarse->fine fine pass then ships only ``(centers, tail_start)`` plus
     the static ``(refine_stride, refine_width)`` and the windows are
     gathered on device.
+
+    Each call copies its results back ONCE: the jitted solve returns
+    every output packed into one float64 ``(S, K)`` device buffer (layout
+    in :func:`_build_grid_solve`), which :func:`_fetch_packed` pulls back
+    and cuts into the dict of host arrays that :func:`_fetch` would give
+    (same keys, dtypes, shapes and values), counting one ``d2h_arrays``.
     """
 
     def build(objective):
@@ -342,8 +425,8 @@ def grid_objective_builder(value_fn, exact_arq: bool = False) -> Callable:
             # conversion, host-to-device copies, launch), the wait on
             # the device, the copies back
             with span("planner.dispatch"):
-                dense_fn, win_fn = _grid_solve_for(kernel_table_version(),
-                                                   value_fn, exact_arq)
+                dense_fn, win_fn, layout = _grid_solve_for(
+                    kernel_table_version(), value_fn, exact_arq)
                 arrays = dict(arrays)
                 stride = arrays.pop("refine_stride", None)
                 width = arrays.pop("refine_width", None)
@@ -352,16 +435,17 @@ def grid_objective_builder(value_fn, exact_arq: bool = False) -> Callable:
                     if shard:
                         arrays = _maybe_shard(arrays, arrays["N"].shape[0])
                     if stride is None:
-                        out = dense_fn(sigma=consts.variance_floor,
+                        buf = dense_fn(sigma=consts.variance_floor,
                                        e0=consts.init_gap,
                                        contraction=consts.contraction,
                                        **arrays)
                     else:
-                        out = win_fn(sigma=consts.variance_floor,
+                        buf = win_fn(sigma=consts.variance_floor,
                                      e0=consts.init_gap,
                                      contraction=consts.contraction,
                                      stride=stride, width=width, **arrays)
-            return _fetch(out)
+            return _fetch_packed(buf, layout(arrays["rates"], arrays["grid"],
+                                             width))
         solve.supports_refine_windows = True
         return solve
 

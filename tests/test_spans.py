@@ -296,3 +296,28 @@ def test_idle_worker_shows_in_a_trace_begun_mid_wait(tmp_path):
              if name == "serve.wait"]
     # the idle wait is cut into pieces, so the trace holds most of it
     assert sum(z - a for a, z in waits) >= 0.5 * 0.2e9
+
+
+def test_grid_solves_copy_back_once_per_call(service):
+    """A grid solve returns its outputs packed into one device buffer: a
+    served chunk of grid-objective requests records one copy back per
+    jitted call, dense or two-pass."""
+    before = service.spans.recorded
+    _serve(service, 12, seed=64, mode="dense")
+    _serve(service, 12, seed=65, mode="refine")
+    spans = service.spans.snapshot()[-(service.spans.recorded - before):]
+    solved = [s for s in spans if s.dispatches]
+    assert solved
+    for s in solved:
+        assert s.d2h_arrays == s.dispatches, s
+    reqs = synth_requests(6, seed=66, dup_frac=0.0, n_classes=6,
+                          models=("erasure",), n_max=4096)
+    runtime.open_record()
+    try:
+        FleetPlanner(grid_size=128).plan_many(
+            reqs, CONSTS, cache=PlanCache(maxsize=64), pad_to=8,
+            grid_mode="refine")
+        _, counts, _ = runtime.take_record()
+    finally:
+        runtime.close_record()
+    assert counts["d2h_arrays"] == counts["dispatches"] == 2

@@ -131,8 +131,8 @@ def test_grid_solve_compiles_in_f64(one_chip, exact_arq):
     scs = synth_requests(256, seed=0, models=ALL_MODELS)
     batch = ScenarioBatch.from_scenarios(scs)
     arrays = FleetPlanner._solve_arrays(batch, fleet_grid(batch.N, 128))
-    dense, _ = _grid_solve_for(kernel_table_version(), _corollary1_values,
-                               exact_arq)
+    dense, _, _ = _grid_solve_for(kernel_table_version(),
+                                  _corollary1_values, exact_arq)
     with jax.enable_x64(True):
         consts = {k: np.float64(v) for k, v in
                   dict(sigma=0.1, e0=1.0, contraction=0.5).items()}

@@ -129,9 +129,9 @@ def _fetch(out: dict) -> dict:
     arrays, as two leaves: one transfer per array.
 
     The Monte-Carlo solve and the federated round fetch this way: their
-    outputs differ from the grid solves' and no benchmark cell measures
-    them, so they keep the plain per-array path.  The grid solves pack
-    their outputs into one buffer (:func:`_fetch_packed`)."""
+    outputs differ from the grid solves', so they keep the plain
+    per-array path.  The grid solves pack their outputs into one buffer
+    (:func:`_fetch_packed`)."""
     with span("planner.device_wait"):
         jax.block_until_ready(out)
     with span("planner.fetch"):
@@ -746,6 +746,28 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
     return montecarlo_solve
 
 
+def _count_mc(arrays: dict, runs: int, max_updates: int,
+              sharded: bool) -> None:
+    """One Monte-Carlo pass on the open chunk record, reckoned on the host
+    from the batch's arrays.  A lane is one simulated trajectory (run x
+    scenario x rate x grid point, bucket padding included):
+    ``mc_lane_slots`` counts every lane's padded timeline, and
+    ``mc_live_slots`` each lane's slots before its deadline
+    ``floor(T / tau_p)``, capped by the pass's horizon (slots before a
+    lane's first block arrives are live but masked).
+    ``mc_sharded_dispatches`` counts a pass laid over every local
+    device."""
+    per_scenario = arrays["rates"].shape[1] * arrays["grid"].shape[-1]
+    horizon = np.minimum(np.floor(np.asarray(arrays["T"])
+                                  / np.asarray(arrays["tau_p"])),
+                         max_updates)
+    count("mc_lane_slots",
+          runs * per_scenario * horizon.shape[0] * max_updates)
+    count("mc_live_slots", runs * per_scenario * int(horizon.sum()))
+    if sharded:
+        count("mc_sharded_dispatches")
+
+
 def montecarlo_builder(objective) -> Callable:
     """Kernel builder for ``MonteCarloObjective``: pads the shared update
     timeline to the next power of two over the batch (masked slots no-op,
@@ -793,6 +815,8 @@ def montecarlo_builder(objective) -> Callable:
             and lanes % n_dev == 0
         with span("planner.dispatch"):
             _count_in(len(arrays))
+            _count_mc(arrays, int(mc_seeds or objective.n_runs),
+                      max_updates, shard)
             with jax.enable_x64(True):
                 if shard:
                     arrays = _maybe_shard(arrays, S)

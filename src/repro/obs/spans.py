@@ -62,9 +62,13 @@ LEAVES = ("serve.wait", "serve.take", "serve.pad", "planner.cache_lookup",
 
 #: Counters of a chunk's record: jitted calls, arrays passed in and
 #: arrays converted back, live (unpadded) lanes and unique lanes
-#: (cache misses after in-batch dedup).
+#: (cache misses after in-batch dedup), and of its Monte-Carlo passes:
+#: simulated lane-slots dispatched (every run's lanes times the padded
+#: timeline), the lane-slots before each lane's deadline, and the passes
+#: that ran across every local device.
 COUNTERS = ("dispatches", "h2d_arrays", "d2h_arrays", "lanes_live",
-            "lanes_unique")
+            "lanes_unique", "mc_lane_slots", "mc_live_slots",
+            "mc_sharded_dispatches")
 
 
 def leaf_field(name: str) -> str:
@@ -112,6 +116,9 @@ class RequestSpan:
     d2h_arrays: int = 0
     lanes_live: int = 0
     lanes_unique: int = 0
+    mc_lane_slots: int = 0
+    mc_live_slots: int = 0
+    mc_sharded_dispatches: int = 0
 
     @property
     def phase_sum(self) -> float:

@@ -3,6 +3,10 @@ columns and identifiers, the garbage-collection hook, and the program
 spans a profiler trace of a served stream shows."""
 import gc
 import glob
+import json
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -11,6 +15,7 @@ import pytest
 
 from repro.core import BoundConstants
 from repro.fleet import FleetPlanner, PlanCache
+from repro.fleet.objective_kernels import pow2ceil
 from repro.obs import LEAVES, RequestSpan, SpanRecorder, runtime
 from repro.serve import (MicroBatcher, PlanningService, PlanRequest,
                          ServiceConfig, synth_requests)
@@ -211,6 +216,9 @@ def test_batcher_stamps_flush_ids_and_times_its_leaves():
 
     b = MicroBatcher(plan_group, max_batch=3, flush_interval=0.005)
     b.start()
+    # let the worker reach its idle wait first: a full batch queued before
+    # it looks is taken at once, with no wait in that flush's record
+    time.sleep(0.05)
     try:
         for batch in ((0, 1, 2), (3,)):
             futs = [b.submit(PlanRequest(scenario=i)) for i in batch]
@@ -321,3 +329,69 @@ def test_grid_solves_copy_back_once_per_call(service):
     finally:
         runtime.close_record()
     assert counts["d2h_arrays"] == counts["dispatches"] == 2
+
+
+_MC_COUNTS_SCRIPT = """
+import json
+import numpy as np, jax
+from repro.core import BoundConstants
+from repro.core.objectives import MonteCarloObjective
+from repro.core.scenario import ErasureLink, Scenario
+from repro.fleet import FleetPlanner
+from repro.obs import runtime
+
+rng = np.random.default_rng(0)
+X = rng.normal(size=(48, 4))
+y = X @ rng.normal(size=4) + 0.1 * rng.normal(size=48)
+mc = MonteCarloObjective(X=X, y=y, n_runs=2, alpha=1e-3, seed=0)
+scs = [Scenario(N=int(n), T=1.3 * n, n_o=float(o), tau_p=tau,
+                link=ErasureLink(beta=0.4, p_base=0.05, rates=(1.0, 2.0)))
+       for n, o, tau in zip((256, 384, 512, 320, 288, 448, 352, 400),
+                            (20, 90, 45, 150, 60, 10, 120, 75),
+                            (1.0, 0.5, 2.0, 1.0, 0.5, 1.0, 2.0, 1.0))]
+consts = BoundConstants(L=1.908, c=0.061, M=1.0, M_G=1.0, D=1.0,
+                        alpha=1e-4)
+planner = FleetPlanner(grid_size=8, mc_impl="scan")
+out = {}
+for n, pad_to in ((8, 8), (6, 6), (5, 8)):
+    runtime.open_record()
+    planner.plan_many(scs[:n], consts, pad_to=pad_to, objective=mc)
+    out[f"{n}/{pad_to}"] = runtime.take_record()[1]
+    runtime.close_record()
+print("COUNTS", json.dumps({"devices": jax.device_count(), "counts": out}))
+"""
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_montecarlo_chunk_counts_lane_slots_and_sharded_passes(devices):
+    """Each Monte-Carlo pass counts its lanes' padded slots and the slots
+    before each lane's deadline (pad lanes repeat the smallest scenario),
+    and counts a sharded dispatch only where the batch splits evenly over
+    every device: never on one device, nor for a batch of 6 on four."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
+               PYTHONPATH=os.pathsep.join(
+                   ["src", os.environ.get("PYTHONPATH", "")]))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", _MC_COUNTS_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=300,
+                         cwd=repo)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("COUNTS")]
+    got = json.loads(line[0].split(" ", 1)[1])
+    assert got["devices"] == devices
+    N = (256, 384, 512, 320, 288, 448, 352, 400)
+    tau = (1.0, 0.5, 2.0, 1.0, 0.5, 1.0, 2.0, 1.0)
+    per_scenario = 2 * 8        # rates x grid points
+    for key, counts in got["counts"].items():
+        n, pad_to = (int(v) for v in key.split("/"))
+        small = min(range(n), key=lambda i: N[i])
+        rows = list(range(n)) + [small] * (pad_to - n)
+        total = [int(np.floor(1.3 * N[i] / tau[i])) for i in rows]
+        horizon = pow2ceil(max(total))
+        assert counts["dispatches"] == 1
+        assert counts["mc_lane_slots"] == 2 * per_scenario * pad_to * horizon
+        assert counts["mc_live_slots"] == 2 * per_scenario * sum(
+            min(t, horizon) for t in total)
+        sharded = devices > 1 and pad_to % devices == 0
+        assert counts.get("mc_sharded_dispatches", 0) == int(sharded), key

@@ -552,6 +552,11 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
 
         slab = min(MC_SLAB, max_updates)
         nslab = max_updates // slab
+        # each lane's deadline as a slot count: no slot from it on
+        # updates, so the pallas engine stops each lane block there and
+        # the whole pass after the batch's last live slab
+        lane_hi = jnp.minimum(lane_total, max_updates).astype(jnp.int32)
+        live_slabs = (jnp.max(lane_hi) + (slab - 1)) // slab
         c_reg = jnp.float32(2.0 * alpha * lam / n)
         c_2a = jnp.float32(-2.0 * alpha)
 
@@ -609,7 +614,7 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
             W, _ = jax.lax.scan(inner, W, (ix, m), unroll=2)
             return W
 
-        def pallas_slab(W, Xs, ys, ix, m):
+        def pallas_slab(W, Xs, ys, ix, m, j0):
             from repro.kernels.mc_ridge import mc_ridge_slab
             slab_fn = partial(mc_ridge_slab, alpha=alpha, lam=lam,
                               fused=crn, interpret=interpret,
@@ -620,9 +625,9 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
                 slab_fn = jax.shard_map(
                     slab_fn, mesh=mesh,
                     in_specs=(P("fleet"), P(), P(), P(None, "fleet"),
-                              P(None, "fleet")),
+                              P(None, "fleet"), P("fleet"), P()),
                     out_specs=P("fleet"), check_vma=False)
-            return slab_fn(W, Xs, ys, ix, m)
+            return slab_fn(W, Xs, ys, ix, m, lane_hi, j0)
 
         def per_run_exact_scan(r):
             # the reference engine: per-slot split + randint INSIDE the
@@ -656,38 +661,38 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
                                          jnp.arange(max_updates))
             return W_fin
 
-        def per_run_slabbed(r):
-            # table-driven engines: outer scan over slabs; each slab's
-            # (slab, L) tables feed either the lean jnp inner scan or
-            # one pallas_call — both consume IDENTICAL tables, so the
-            # two engines agree bitwise
+        def per_run_pallas(r):
+            # the pallas engine, one run: a loop over the slabs up to the
+            # batch's last live one; each slab's (slab, L) tables, the
+            # same the scan engines build, feed one pallas_call.  Slabs
+            # past every deadline would only mask, so neither their
+            # tables nor their calls are made; the uniforms are still
+            # drawn for the whole horizon, so the stream is unchanged
             key = run_key(r)
             kp, kw, ks = jax.random.split(key, 3)
             perm = jax.random.permutation(kp, n)
             Xs, ys = X[perm], y[perm]
             w0 = jax.random.normal(kw, (d,), jnp.float32)
             W0 = jnp.broadcast_to(w0, (L, d))
-            run_slab = pallas_slab if mc_impl == "pallas" else scan_slab
 
             if crn:
                 u = jax.random.uniform(ks, (max_updates,),
                                        jnp.float32).reshape(nslab, slab)
 
-                def outer(W, inp):
-                    s, u_s = inp
-                    ix, m = crn_tables(s * slab, u_s)
-                    return run_slab(W, Xs, ys, ix, m), None
+                def outer(s, W):
+                    ix, m = crn_tables(s * slab, u[s])
+                    return pallas_slab(W, Xs, ys, ix, m, s * slab)
 
-                W_fin, _ = jax.lax.scan(outer, W0,
-                                        (jnp.arange(nslab), u))
-            else:
-                def outer(carry, s):
-                    W, k = carry
-                    k, (ix, m) = exact_tables(k, s * slab)
-                    return (run_slab(W, Xs, ys, ix, m), k), None
+                return jax.lax.fori_loop(np.int32(0), live_slabs, outer,
+                                         W0)
 
-                (W_fin, _), _ = jax.lax.scan(outer, (W0, ks),
-                                             jnp.arange(nslab))
+            def outer(s, carry):
+                W, k = carry
+                k, (ix, m) = exact_tables(k, s * slab)
+                return pallas_slab(W, Xs, ys, ix, m, s * slab), k
+
+            W_fin, _ = jax.lax.fori_loop(np.int32(0), live_slabs, outer,
+                                         (W0, ks))
             return W_fin
 
         def crn_scan_all_runs():
@@ -731,7 +736,7 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
         if mc_impl == "pallas":
             # python loop over runs: vmapping a pallas_call would batch
             # the kernel grid; runs are few, so unrolled calls are fine
-            W_fin = jnp.stack([per_run_slabbed(r) for r in range(runs)])
+            W_fin = jnp.stack([per_run_pallas(r) for r in range(runs)])
         elif crn:
             W_fin = crn_scan_all_runs()
         else:
@@ -746,24 +751,62 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
     return montecarlo_solve
 
 
-def _count_mc(arrays: dict, runs: int, max_updates: int,
-              sharded: bool) -> None:
+def _mc_horizons(arrays: dict, max_updates: int) -> np.ndarray:
+    """Each scenario's deadline in update slots, ``floor(T / tau_p)``,
+    capped by the pass's horizon: every lane of the scenario updates only
+    at slots below it."""
+    return np.minimum(np.floor(np.asarray(arrays["T"])
+                               / np.asarray(arrays["tau_p"])),
+                      max_updates).astype(np.int64)
+
+
+def _mc_order(horizon: np.ndarray, n_dev: int) -> np.ndarray:
+    """The scenario order the pallas engine is fed in: by deadline, dealt
+    over ``n_dev`` devices (device ``c`` holds the sorted positions ``c,
+    c + n_dev, ...`` in ascending order), so each lane block's longest
+    lane is close to its shortest and every device gets a like share."""
+    by_deadline = np.argsort(horizon, kind="stable")
+    return by_deadline.reshape(-1, n_dev).T.reshape(-1)
+
+
+def _mc_run_slots(horizon: np.ndarray, per_scenario: int,
+                  n_dev: int) -> int:
+    """Lane-slots the pallas kernel steps through in one run: each
+    device's lanes in ``BLOCK_L``-lane blocks, every lane stepping to its
+    block's longest deadline (``block_steps`` summed over the slabs)."""
+    from repro.kernels.mc_ridge import BLOCK_L
+    total = 0
+    for part in np.split(np.repeat(horizon, per_scenario), n_dev):
+        pad = (-part.size) % BLOCK_L
+        steps = np.pad(part, (0, pad)).reshape(-1, BLOCK_L).max(axis=1)
+        real = np.full(steps.size, BLOCK_L)
+        real[-1] -= pad
+        total += int(steps @ real)
+    return total
+
+
+def _count_mc(arrays: dict, runs: int, max_updates: int, sharded: bool,
+              kernel_devices: int = 0) -> None:
     """One Monte-Carlo pass on the open chunk record, reckoned on the host
     from the batch's arrays.  A lane is one simulated trajectory (run x
     scenario x rate x grid point, bucket padding included):
-    ``mc_lane_slots`` counts every lane's padded timeline, and
+    ``mc_lane_slots`` counts every lane's padded timeline,
     ``mc_live_slots`` each lane's slots before its deadline
     ``floor(T / tau_p)``, capped by the pass's horizon (slots before a
-    lane's first block arrives are live but masked).
+    lane's first block arrives are live but masked), and
+    ``mc_run_slots`` the slots the pallas kernel steps each lane through
+    (its block's longest deadline), over ``kernel_devices`` devices in
+    the arrays' order; the scan engines run no kernel and count none.
     ``mc_sharded_dispatches`` counts a pass laid over every local
     device."""
     per_scenario = arrays["rates"].shape[1] * arrays["grid"].shape[-1]
-    horizon = np.minimum(np.floor(np.asarray(arrays["T"])
-                                  / np.asarray(arrays["tau_p"])),
-                         max_updates)
+    horizon = _mc_horizons(arrays, max_updates)
     count("mc_lane_slots",
           runs * per_scenario * horizon.shape[0] * max_updates)
     count("mc_live_slots", runs * per_scenario * int(horizon.sum()))
+    if kernel_devices:
+        count("mc_run_slots", runs * _mc_run_slots(horizon, per_scenario,
+                                                   kernel_devices))
     if sharded:
         count("mc_sharded_dispatches")
 
@@ -780,6 +823,12 @@ def montecarlo_builder(objective) -> Callable:
     own scenarios' lanes.  Requires both ``S`` and the lane count to
     divide the device count; otherwise the solve runs unsharded (single
     device is the common case and is bitwise-unchanged by this path).
+
+    The pallas engine gets the scenarios in deadline order
+    (:func:`_mc_order`), so its lane blocks stop early together, and its
+    outputs are put back in the caller's order.  Lanes are independent
+    (one shared uniform per slot, lane-wise losses), so the order changes
+    no plan.
     """
 
     def solve(arrays, consts, shard, batch):
@@ -813,10 +862,18 @@ def montecarlo_builder(objective) -> Callable:
         lanes = S * arrays["rates"].shape[1] * arrays["grid"].shape[-1]
         shard = bool(shard) and n_dev > 1 and S % n_dev == 0 \
             and lanes % n_dev == 0
+        kernel_devices = 0
+        order = None
         with span("planner.dispatch"):
+            if mc_impl == "pallas":
+                kernel_devices = n_dev if shard else 1
+                order = _mc_order(_mc_horizons(arrays, max_updates),
+                                  kernel_devices)
+                arrays = {k: np.asarray(v)[order]
+                          for k, v in arrays.items()}
             _count_in(len(arrays))
             _count_mc(arrays, int(mc_seeds or objective.n_runs),
-                      max_updates, shard)
+                      max_updates, shard, kernel_devices)
             with jax.enable_x64(True):
                 if shard:
                     arrays = _maybe_shard(arrays, S)
@@ -824,7 +881,13 @@ def montecarlo_builder(objective) -> Callable:
                          mc_impl=str(mc_impl),
                          mc_seeds=None if mc_seeds is None
                          else int(mc_seeds), **arrays)
-        return _fetch(out)
+        res = _fetch(out)
+        if order is not None:
+            with span("planner.fetch"):
+                back = np.empty_like(order)
+                back[order] = np.arange(S)
+                res = {k: v[back] for k, v in res.items()}
+        return res
 
     solve.supports_mc_impl = True
     return solve

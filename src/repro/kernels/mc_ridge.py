@@ -20,7 +20,12 @@ the ``lax.scan`` reference bit-for-bit.
 
 Grid: one program per 128-lane block; lanes are padded to a block
 multiple with ``m = 0`` rows (a dead lane's weights pass through both
-update forms unchanged).
+update forms unchanged).  Each block steps through only as many slots
+as its lanes can use: given every lane's deadline ``hi``, the wrapper
+reduces the slab's slots before it to one count per block and hands the
+counts to the kernel as a scalar prefetch, so a block whose lanes are
+all past their deadline runs no slot at all.  A masked slot leaves the
+weights unchanged, so stopping early changes no bit.
 
 ``fused=True`` applies the update in the algebraically-rearranged
 affine form ``W <- c1 * W + c2 * xr`` used by the common-random-numbers
@@ -39,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pipeline import ridge_dot
 
@@ -53,9 +59,12 @@ _EXACT = jax.lax.Precision.HIGHEST
 #: cannot lower.
 _I0 = np.int32(0)
 
+#: Lanes per program: one (d, 128) float32 weight tile.
+BLOCK_L = 128
 
-def _mc_ridge_kernel(xs_ref, ys_ref, ix_ref, m_ref, w_ref, o_ref, *,
-                     slab: int, n: int, alpha: float, lam: float,
+
+def _mc_ridge_kernel(steps_ref, xs_ref, ys_ref, ix_ref, m_ref, w_ref,
+                     o_ref, *, n: int, alpha: float, lam: float,
                      fused: bool, ordered: bool):
     Xs = xs_ref[...]                                   # (d, n) f32
     ys = ys_ref[...]                                   # (1, n) f32
@@ -81,22 +90,40 @@ def _mc_ridge_kernel(xs_ref, ys_ref, ix_ref, m_ref, w_ref, o_ref, *,
         g = 2.0 * (dot - yr) * xr + 2.0 * lam / n * W
         return jnp.where(mr > 0.0, W - alpha * g, W)
 
-    o_ref[...] = jax.lax.fori_loop(np.int32(0), np.int32(slab), body,
-                                   w_ref[...])
+    steps = steps_ref[pl.program_id(0)]
+    o_ref[...] = jax.lax.fori_loop(np.int32(0), steps, body, w_ref[...])
+
+
+def block_steps(hi, j0, slab: int, block_l: int = BLOCK_L):
+    """Slots each ``block_l``-lane block of one slab runs: the most any of
+    its lanes has before its deadline, ``clip(hi - j0, 0, slab)``.
+
+    ``hi``: (L,) int32 per-lane deadline (a lane updates only at slots
+    below it); ``j0``: the slab's first slot.  Lanes past ``L`` up to the
+    block multiple count as deadline 0.  Returns (blocks,) int32."""
+    hi = jnp.asarray(hi, jnp.int32)
+    left = jnp.clip(hi - jnp.asarray(j0, jnp.int32), np.int32(0),
+                    np.int32(slab))
+    left = jnp.pad(left, (0, (-left.shape[0]) % block_l))
+    return jnp.max(left.reshape(-1, block_l), axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "lam", "fused",
                                              "interpret", "ordered",
                                              "block_l"))
-def mc_ridge_slab(W, Xs, ys, ix, m, *, alpha: float, lam: float,
-                  fused: bool, interpret: bool = False,
-                  ordered: bool = True, block_l: int = 128):
+def mc_ridge_slab(W, Xs, ys, ix, m, hi=None, j0=0, *, alpha: float,
+                  lam: float, fused: bool, interpret: bool = False,
+                  ordered: bool = True, block_l: int = BLOCK_L):
     """Advance all lanes through one slab of update slots.
 
     ``W``: (L, d) f32 per-lane weights; ``Xs``: (n, d) f32 permuted
     training rows; ``ys``: (n,) f32 targets; ``ix``: (slab, L) int32
     sampled row per (slot, lane); ``m``: (slab, L) f32, 1.0 where the
-    lane updates at that slot.  Returns the updated (L, d) weights.
+    lane updates at that slot.  ``hi``: optional (L,) int32 per-lane
+    deadline and ``j0`` the slab's first slot: each block then stops at
+    its lanes' last slot before their deadline (:func:`block_steps`);
+    ``m`` must be 0 from each lane's deadline on.  Without ``hi`` every
+    block runs the whole slab.  Returns the updated (L, d) weights.
     ``ordered`` is :func:`~repro.core.pipeline.ridge_dot`'s lane-dot form;
     Mosaic lowers only the ordered one.
     """
@@ -110,23 +137,30 @@ def mc_ridge_slab(W, Xs, ys, ix, m, *, alpha: float, lam: float,
         ix = jnp.pad(ix, ((0, 0), (0, pad)))
         m = jnp.pad(m, ((0, 0), (0, pad)))             # dead lanes: m = 0
     lp = L + pad
+    if hi is None:
+        steps = jnp.full((lp // block_l,), slab, jnp.int32)
+    else:
+        steps = block_steps(hi, j0, slab, block_l)
 
     kernel = functools.partial(
-        _mc_ridge_kernel, slab=slab, n=n, alpha=float(alpha),
-        lam=float(lam), fused=fused, ordered=ordered)
+        _mc_ridge_kernel, n=n, alpha=float(alpha), lam=float(lam),
+        fused=fused, ordered=ordered)
     out = pl.pallas_call(
         kernel,
-        grid=(lp // block_l,),
-        in_specs=[
-            pl.BlockSpec((d, n), lambda i: (_I0, _I0)),
-            pl.BlockSpec((1, n), lambda i: (_I0, _I0)),
-            pl.BlockSpec((slab, block_l), lambda i: (_I0, i)),
-            pl.BlockSpec((slab, block_l), lambda i: (_I0, i)),
-            pl.BlockSpec((d, block_l), lambda i: (_I0, i)),
-        ],
-        out_specs=pl.BlockSpec((d, block_l), lambda i: (_I0, i)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(lp // block_l,),
+            in_specs=[
+                pl.BlockSpec((d, n), lambda i, s: (_I0, _I0)),
+                pl.BlockSpec((1, n), lambda i, s: (_I0, _I0)),
+                pl.BlockSpec((slab, block_l), lambda i, s: (_I0, i)),
+                pl.BlockSpec((slab, block_l), lambda i, s: (_I0, i)),
+                pl.BlockSpec((d, block_l), lambda i, s: (_I0, i)),
+            ],
+            out_specs=pl.BlockSpec((d, block_l), lambda i, s: (_I0, i)),
+        ),
         out_shape=jax.ShapeDtypeStruct((d, lp), jnp.float32),
         interpret=interpret,
-    )(Xs.T.astype(jnp.float32), ys[None, :].astype(jnp.float32),
+    )(steps, Xs.T.astype(jnp.float32), ys[None, :].astype(jnp.float32),
       ix.astype(jnp.int32), m.astype(jnp.float32), Wt)
     return out[:, :L].T

@@ -64,11 +64,12 @@ LEAVES = ("serve.wait", "serve.take", "serve.pad", "planner.cache_lookup",
 #: arrays converted back, live (unpadded) lanes and unique lanes
 #: (cache misses after in-batch dedup), and of its Monte-Carlo passes:
 #: simulated lane-slots dispatched (every run's lanes times the padded
-#: timeline), the lane-slots before each lane's deadline, and the passes
-#: that ran across every local device.
+#: timeline), the lane-slots before each lane's deadline, the lane-slots
+#: the ``mc_ridge`` kernel stepped through (each lane to its block's
+#: longest deadline), and the passes that ran across every local device.
 COUNTERS = ("dispatches", "h2d_arrays", "d2h_arrays", "lanes_live",
             "lanes_unique", "mc_lane_slots", "mc_live_slots",
-            "mc_sharded_dispatches")
+            "mc_run_slots", "mc_sharded_dispatches")
 
 
 def leaf_field(name: str) -> str:
@@ -118,6 +119,7 @@ class RequestSpan:
     lanes_unique: int = 0
     mc_lane_slots: int = 0
     mc_live_slots: int = 0
+    mc_run_slots: int = 0
     mc_sharded_dispatches: int = 0
 
     @property

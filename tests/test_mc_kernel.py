@@ -94,6 +94,40 @@ def test_mc_ridge_slab_dead_lane_passthrough():
         np.testing.assert_array_equal(out[2], W[2])
 
 
+@pytest.mark.parametrize("fused", [False, True])
+def test_mc_ridge_slab_stops_each_block_at_its_last_live_slot(fused):
+    """Given lane deadlines ``hi`` and the slab's first slot ``j0``, each
+    128-lane block runs only to its lanes' last live slot: here a block
+    with no slot left, one with part of the slab and one with all of it
+    (the last block padded).  The weights are bitwise the whole-slab
+    call's on the same tables, and meet the numpy oracle."""
+    from repro.kernels.mc_ridge import block_steps
+    rng = np.random.default_rng(5)
+    L, d, n, slab, j0 = 300, 4, 16, 24, 48
+    hi = np.empty(L, np.int32)
+    hi[:128] = rng.integers(0, j0 + 1, size=128)          # all past
+    hi[128:256] = rng.integers(j0, j0 + 10, size=128)     # partial
+    hi[256:] = rng.integers(j0, j0 + 2 * slab, size=L - 256)
+    hi[260] = j0 + slab                                   # a full block
+    W = rng.normal(size=(L, d)).astype(np.float32)
+    Xs = rng.normal(size=(n, d)).astype(np.float32)
+    ys = rng.normal(size=n).astype(np.float32)
+    ix = rng.integers(0, n, size=(slab, L)).astype(np.int32)
+    live = (j0 + np.arange(slab))[:, None] < hi[None, :]
+    m = (live & (rng.random(size=(slab, L)) < 0.8)).astype(np.float32)
+    steps = np.asarray(block_steps(hi, j0, slab))
+    assert steps[0] == 0 and 0 < steps[1] < slab and steps[2] == slab
+    assert steps[1] == np.max(hi[128:256]) - j0
+    kw = dict(alpha=1e-3, lam=0.1, fused=fused, interpret=True)
+    bounded = np.asarray(mc_ridge_slab(W, Xs, ys, ix, m, hi, np.int32(j0),
+                                       **kw))
+    whole = np.asarray(mc_ridge_slab(W, Xs, ys, ix, m, **kw))
+    np.testing.assert_array_equal(bounded, whole)
+    np.testing.assert_array_equal(bounded[:128], W[:128])
+    ref = mc_ridge_ref(W, Xs, ys, ix, m, alpha=1e-3, lam=0.1, fused=fused)
+    np.testing.assert_allclose(bounded, ref, rtol=2e-5, atol=2e-6)
+
+
 # ---------------------------------------------------------------------------
 # engine equivalence: pallas (interpret) bitwise == lax.scan
 # ---------------------------------------------------------------------------
@@ -120,6 +154,125 @@ def test_pallas_engine_bitwise_matches_scan(crn):
                                   np.asarray(pallas.bound_value))
     np.testing.assert_array_equal(np.asarray(scan.bound_grid),
                                   np.asarray(pallas.bound_grid))
+
+
+def _deadline_scenarios(slots):
+    """One scenario per deadline in ``slots`` (``floor(T / tau_p)``),
+    with sizes, overheads and step times that differ."""
+    link = ErasureLink(beta=0.4, p_base=0.05, rates=(1.0, 2.0))
+    taus = (0.5, 1.0, 2.0)
+    return [Scenario(N=256 + 37 * i, T=float(h) * taus[i % 3],
+                     n_o=float(10 + 13 * i), tau_p=taus[i % 3], link=link)
+            for i, h in enumerate(slots)]
+
+
+def _solve_three_ways(mc, scs, pad_to):
+    """The pallas engine as served (scenarios in deadline order, each lane
+    block bounded), the jitted pallas solve on the caller's order, and the
+    CRN scan engine, which steps every lane through the whole horizon."""
+    from repro.fleet.link_kernels import kernel_table_version
+    from repro.fleet.objective_kernels import _mc_solve_for, pow2ceil
+    from repro.fleet.planner import _pad_batch
+    batch = ScenarioBatch.from_scenarios(_pad_batch(scs, pad_to))
+    grid = fleet_grid(batch.N, 6)
+    arrays = FleetPlanner._solve_arrays(batch, grid)
+    solve = fleet_solve(mc)
+    served = solve(dict(arrays, mc_impl="pallas"), CONSTS, False, batch)
+    scan = solve(dict(arrays), CONSTS, False, batch)
+    with jax.enable_x64(True):
+        direct = _mc_solve_for(mc, kernel_table_version(), True)(
+            max_updates=pow2ceil(batch.max_updates), mc_impl="pallas",
+            **arrays)
+    return served, jax.device_get(direct), scan, batch
+
+
+@pytest.mark.parametrize("crn", [False, True])
+def test_pallas_solve_with_deadlines_from_one_slot_to_the_horizon(crn):
+    """Deadlines from 1 slot to the whole 1,024-slot horizon, in no order,
+    plus padding scenarios: the served pallas solve, which sorts the
+    scenarios and stops lane blocks and slabs early, returns every output
+    bitwise the unsorted pallas solve's and the full-horizon scan
+    engine's, in the caller's order."""
+    X, y = _ridge_data()
+    mc = MonteCarloObjective(X=X, y=y, n_runs=2, alpha=1e-3, seed=0,
+                             crn=crn)
+    scs = _deadline_scenarios((700, 1, 1024, 90, 300, 5, 511))
+    served, direct, scan, batch = _solve_three_ways(mc, scs, pad_to=12)
+    assert batch.max_updates == 1024
+    assert set(served) == set(scan) == set(direct)
+    for key in scan:
+        np.testing.assert_array_equal(served[key], scan[key], err_msg=key)
+        np.testing.assert_array_equal(served[key], direct[key],
+                                      err_msg=key)
+
+
+def test_pallas_solve_where_every_lane_runs_the_whole_horizon():
+    """Every deadline at the 512-slot horizon: no block or slab stops
+    early, and the served solve is bitwise the scan engine's."""
+    X, y = _ridge_data()
+    mc = MonteCarloObjective(X=X, y=y, n_runs=2, alpha=1e-3, seed=0,
+                             crn=True)
+    served, direct, scan, batch = _solve_three_ways(
+        mc, _deadline_scenarios((512,) * 4), pad_to=4)
+    assert batch.max_updates == 512
+    for key in scan:
+        np.testing.assert_array_equal(served[key], scan[key], err_msg=key)
+        np.testing.assert_array_equal(served[key], direct[key],
+                                      err_msg=key)
+
+
+_SHARDED_ORDER_SCRIPT = """
+import json
+import jax, numpy as np
+assert jax.device_count() == 4, jax.devices()
+import test_mc_kernel as t
+from repro.core import MonteCarloObjective
+from repro.fleet import ScenarioBatch
+from repro.fleet.objective_kernels import _mc_horizons, _mc_order, fleet_solve
+from repro.fleet.planner import FleetPlanner
+
+X, y = t._ridge_data()
+mc = MonteCarloObjective(X=X, y=y, n_runs=2, alpha=1e-3, seed=0, crn=True)
+scs = t._deadline_scenarios((700, 1, 1024, 90, 300, 5, 511, 64))
+batch = ScenarioBatch.from_scenarios(scs)
+arrays = FleetPlanner._solve_arrays(batch, t.fleet_grid(batch.N, 6))
+solve = fleet_solve(mc)
+sharded = solve(dict(arrays, mc_impl="pallas"), t.CONSTS, True, batch)
+single = solve(dict(arrays, mc_impl="pallas"), t.CONSTS, False, batch)
+scan = solve(dict(arrays), t.CONSTS, False, batch)
+for key in scan:
+    np.testing.assert_array_equal(sharded[key], scan[key], err_msg=key)
+    np.testing.assert_array_equal(single[key], scan[key], err_msg=key)
+order = _mc_order(_mc_horizons(arrays, 1024), 4)
+print("ORDER", json.dumps(order.tolist()))
+"""
+
+
+def test_sharded_pallas_solve_returns_outputs_in_request_order():
+    """On four virtual CPU devices the served pallas solve deals the
+    scenarios over the devices in deadline order, and every output comes
+    back in the caller's order, bitwise the one-device solve's and the
+    scan engine's."""
+    import json
+    import os
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    repo = os.path.dirname(here)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(repo, "src"), here,
+                    os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", _SHARDED_ORDER_SCRIPT],
+                         env=env, capture_output=True, text=True,
+                         timeout=600, cwd=repo)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("ORDER")]
+    # deadlines 700, 1, 1024, 90, 300, 5, 511, 64 sorted: 1, 5, 64, 90,
+    # 300, 511, 700, 1024 (scenarios 1, 5, 7, 3, 4, 6, 0, 2); device c
+    # takes sorted positions c and c + 4
+    assert json.loads(line[0].split(" ", 1)[1]) == [1, 4, 5, 6, 7, 0, 3, 2]
 
 
 # ---------------------------------------------------------------------------

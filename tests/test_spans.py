@@ -395,3 +395,81 @@ def test_montecarlo_chunk_counts_lane_slots_and_sharded_passes(devices):
             min(t, horizon) for t in total)
         sharded = devices > 1 and pad_to % devices == 0
         assert counts.get("mc_sharded_dispatches", 0) == int(sharded), key
+
+
+def test_montecarlo_run_slots_step_each_block_to_its_longest_deadline():
+    """``mc_run_slots`` for a hand-built batch: 64 lanes a scenario, so
+    two scenarios share a 128-lane block and every lane of a block steps
+    to the longer deadline; pad lanes of a part block count nothing.
+    Ordering by deadline shortens the blocks, and live <= run <= lane."""
+    from repro.fleet.objective_kernels import _count_mc, _mc_horizons, \
+        _mc_order
+
+    def counts(deadlines, kernel_devices, order=None):
+        h = np.asarray(deadlines, np.float64)
+        arrays = {"T": 2.0 * h, "tau_p": np.full(h.size, 2.0),
+                  "rates": np.zeros((h.size, 2)),
+                  "grid": np.zeros((h.size, 32))}
+        if order is not None:
+            arrays = {k: v[order] for k, v in arrays.items()}
+        runtime.open_record()
+        try:
+            _count_mc(arrays, 2, 512, False, kernel_devices)
+            return runtime.take_record()[1]
+        finally:
+            runtime.close_record()
+
+    h = [5, 40, 7, 1000, 30, 30, 2, 9]          # 1000 is capped at 512
+    got = counts(h, 1)
+    assert got["mc_run_slots"] == 2 * 128 * (40 + 512 + 30 + 9)
+    assert got["mc_live_slots"] == 2 * 64 * (5 + 40 + 7 + 512 + 30 + 30
+                                             + 2 + 9)
+    assert got["mc_lane_slots"] == 2 * 64 * 8 * 512
+    horizon = _mc_horizons({"T": 2.0 * np.asarray(h, np.float64),
+                            "tau_p": np.full(8, 2.0)}, 512)
+    # sorted: 2 5 7 9 30 30 40 512, in blocks of two
+    got = counts(h, 1, _mc_order(horizon, 1))
+    assert got["mc_run_slots"] == 2 * 128 * (5 + 9 + 30 + 512)
+    # dealt over four devices: (2, 30) (5, 30) (7, 40) (9, 512)
+    got = counts(h, 4, _mc_order(horizon, 4))
+    assert got["mc_run_slots"] == 2 * 128 * (30 + 30 + 40 + 512)
+    assert got["mc_live_slots"] <= got["mc_run_slots"] \
+        <= got["mc_lane_slots"]
+    # three scenarios: one full block (5, 40), one of 64 lanes (7)
+    assert counts(h[:3], 1)["mc_run_slots"] == 2 * (128 * 40 + 64 * 7)
+    # the scan engines run no kernel
+    assert "mc_run_slots" not in counts(h, 0)
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_montecarlo_pallas_pass_counts_run_slots(devices):
+    """A served pallas pass counts its run slots: 16 lanes a scenario, so
+    each device's lanes fill at most one block, which steps to the
+    longest deadline the device holds after the deal by deadline."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
+               PYTHONPATH=os.pathsep.join(
+                   ["src", os.environ.get("PYTHONPATH", "")]))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = _MC_COUNTS_SCRIPT.replace('mc_impl="scan"', 'mc_impl="pallas"')
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=600,
+                         cwd=repo)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("COUNTS")]
+    got = json.loads(line[0].split(" ", 1)[1])
+    N = (256, 384, 512, 320, 288, 448, 352, 400)
+    tau = (1.0, 0.5, 2.0, 1.0, 0.5, 1.0, 2.0, 1.0)
+    for key, counts in got["counts"].items():
+        n, pad_to = (int(v) for v in key.split("/"))
+        small = min(range(n), key=lambda i: N[i])
+        rows = list(range(n)) + [small] * (pad_to - n)
+        total = sorted(int(np.floor(1.3 * N[i] / tau[i])) for i in rows)
+        parts = devices if pad_to % devices == 0 else 1
+        per_part = pad_to // parts
+        # part c holds sorted positions c, c + parts, ...: its longest is
+        # the last of them
+        longest = [total[c + parts * (per_part - 1)] for c in range(parts)]
+        assert counts["mc_run_slots"] == 2 * 16 * per_part * sum(longest)
+        assert counts["mc_live_slots"] <= counts["mc_run_slots"] \
+            <= counts["mc_lane_slots"]

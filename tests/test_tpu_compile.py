@@ -75,6 +75,26 @@ def test_mc_ridge_slab_compiles(one_chip, fused):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("fused", [False, True])
+def test_mc_ridge_slab_compiles_with_lane_deadlines(one_chip, fused):
+    """The slab kernel as the served solve calls it: per-lane deadlines
+    and the slab's first slot, reduced to one slot count per 128-lane
+    block and prefetched as scalars, so each block's slot loop has a trip
+    count known only on the chip."""
+    from repro.kernels.mc_ridge import mc_ridge_slab
+    n, d, lanes, slab = 256, 8, 256 * 32, 256
+    f32, i32 = jnp.float32, jnp.int32
+    s = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt,
+                                                   sharding=one_chip)
+    with jax.enable_x64(True):
+        compiled, _ = _compile(
+            lambda W, X, y, ix, m, hi, j0: mc_ridge_slab(
+                W, X, y, ix, m, hi, j0, alpha=1e-3, lam=0.05, fused=fused),
+            s((lanes, d)), s((n, d)), s((n,)), s((slab, lanes), i32),
+            s((slab, lanes)), s((lanes,), i32), s((), i32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_montecarlo_solve_compiles_with_pallas(one_chip):
     """The served Monte-Carlo solve with the compiled slab kernel: a
     64-request bucket over every link family, a 32-point grid, the

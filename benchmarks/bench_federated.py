@@ -18,8 +18,7 @@ Exercises :mod:`repro.federated` at fleet scale:
      families render and parse on the way); a repeated round is a cache
      hit.
   4. **Artifact** — ``BENCH_federated.json`` at the repo root
-     (provenance-stamped, schema v2), merged into the perf trajectory by
-     ``make_report trajectory`` and uploaded by CI.
+     (provenance-stamped, schema v2), uploaded by CI.
 
 Standalone:  PYTHONPATH=src python -m benchmarks.bench_federated
 """
@@ -60,11 +59,10 @@ def run():
     planner = RoundPlanner(grid_size=GRID_SIZE)
 
     # ---- jitted round solve (warm, then timed) -----------------------------
-    # the prebuilt-batch contract bench_fleet times plan_batch under:
-    # Scenario -> array conversion and the per-device grids are hoisted
-    # out of the timed region on BOTH sides (the scalar loop reads the
-    # Scenario objects directly and its per-device fleet_grid calls are
-    # noise at this scale)
+    # the prebuilt-batch contract: Scenario -> array conversion and the
+    # per-device grids are hoisted out of the timed region on BOTH sides
+    # (the scalar loop reads the Scenario objects directly and its
+    # per-device fleet_grid calls are noise at this scale)
     batch = ScenarioBatch.from_scenarios(population)
     grid = fleet_grid(batch.N, GRID_SIZE)
     t0 = time.perf_counter()
@@ -77,7 +75,7 @@ def run():
                                         grid=grid)
         samples.append(time.perf_counter() - t0)
     # min over repeats: single-core boxes are noisy and the floor is
-    # what the 20x assertion is calibrated against (bench_fleet's rule)
+    # what the 20x assertion is calibrated against
     jit_s = min(samples)
     emit("federated_round", jit_s * 1e6,
          f"S={POPULATION} G={GRID_SIZE} K={plan.k_best} "
@@ -110,7 +108,7 @@ def run():
     assert np.array_equal(plan.n_c, ref.n_c), "per-device n_c differ"
     assert np.array_equal(plan.rate, ref.rate), "per-device rates differ"
     # values may differ in the last ulp where the backend libm disagrees
-    # (the bench_fleet rule: argmins exact, bounds within 1e-9 relative)
+    # (argmins exact, bounds within 1e-9 relative)
     finite = np.isfinite(ref.bound_value)
     assert np.array_equal(finite, np.isfinite(plan.bound_value))
     gap = np.abs(plan.bound_value[finite] - ref.bound_value[finite])

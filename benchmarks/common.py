@@ -11,7 +11,7 @@ ARTIFACTS = os.path.join(os.path.dirname(__file__), "artifacts")
 
 #: BENCH_*.json schema: 2 adds the bench_stamp() provenance fields
 #: (schema_version, generated_utc, git_commit) so runs from different
-#: commits can be lined up into one perf trajectory (make_report.py).
+#: commits can be told apart.
 SCHEMA_VERSION = 2
 
 

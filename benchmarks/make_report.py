@@ -1,25 +1,10 @@
-"""Benchmark report generation.
+"""Benchmark report generation: inject the generated roofline tables
+into ``EXPERIMENTS.md`` placeholders.
 
-Two subcommands:
-
-  * ``roofline`` (default, for backward compatibility) — inject the
-    generated roofline tables into ``EXPERIMENTS.md`` placeholders.
-  * ``trajectory`` — merge the repo-root ``BENCH_fleet.json``,
-    ``BENCH_serve.json`` and ``BENCH_federated.json`` perf artifacts
-    (schema v2: stamped with
-    ``schema_version`` / ``generated_utc`` / ``git_commit`` by
-    ``benchmarks.common.bench_stamp``) into ONE markdown table, so two
-    runs' artifacts can be diffed commit-to-commit as a trajectory:
-
-      PYTHONPATH=src python -m benchmarks.make_report trajectory
-      PYTHONPATH=src python -m benchmarks.make_report trajectory out.md
+  PYTHONPATH=src python -m benchmarks.make_report [roofline] [path]
 """
-import json
-import os
 import re
 import sys
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 MARKERS = {
     "<!-- ROOFLINE_BASELINE_SP -->": ("pod16x16", ""),
@@ -40,103 +25,10 @@ def roofline(path="EXPERIMENTS.md"):
     print("EXPERIMENTS.md tables refreshed")
 
 
-def _load(name):
-    try:
-        with open(os.path.join(ROOT, name)) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return None
-
-
-def _fmt(v, spec=",.0f"):
-    return format(v, spec) if isinstance(v, (int, float)) else "—"
-
-
-def trajectory_table():
-    """One merged markdown table over both perf artifacts.  Tolerates
-    either artifact being absent (a partial bench run still reports) and
-    pre-v2 payloads without the provenance stamp."""
-    fleet, serve = _load("BENCH_fleet.json"), _load("BENCH_serve.json")
-    federated = _load("BENCH_federated.json")
-    lines = ["# Benchmark trajectory", ""]
-    for name, payload in (("BENCH_fleet.json", fleet),
-                          ("BENCH_serve.json", serve),
-                          ("BENCH_federated.json", federated)):
-        if payload is None:
-            lines.append(f"_{name}: absent (run its bench to generate)_")
-            lines.append("")
-            continue
-        commit = payload.get("git_commit") or "unknown"
-        lines.append(
-            f"_{name}: schema v{payload.get('schema_version', 1)}, "
-            f"generated {payload.get('generated_utc', 'unknown')}, "
-            f"commit `{str(commit)[:12]}`_")
-        lines.append("")
-    lines += ["| bench | objective | grid mode | metric | value |",
-              "|---|---|---|---|---|"]
-    if fleet:
-        for row in sorted(fleet.get("rows", []),
-                          key=lambda r: (str(r.get("objective")),
-                                         str(r.get("grid_mode")))):
-            lines.append(
-                f"| fleet | {row.get('objective')} | {row.get('grid_mode')}"
-                f" | plans/sec (S={row.get('S')}) "
-                f"| {_fmt(row.get('plans_per_sec'))} |")
-            if row.get("speedup") is not None:
-                lines.append(
-                    f"| fleet | {row.get('objective')} "
-                    f"| {row.get('grid_mode')} | refine speedup "
-                    f"| {_fmt(row.get('speedup'), '.2f')}x |")
-    if serve:
-        headline = [
-            ("plans/sec", _fmt(serve.get("plans_per_sec"))),
-            ("latency p50 ms", _fmt(serve.get("latency_p50_ms"), ".2f")),
-            ("latency p99 ms", _fmt(serve.get("latency_p99_ms"), ".2f")),
-            ("solve fraction", _fmt(serve.get("solve_fraction"), ".3f")),
-            ("post-warmup traces", _fmt(serve.get("post_warmup_traces"))),
-            ("vs one-shot", _fmt(serve.get("throughput_vs_oneshot"),
-                                 ".2f")),
-        ]
-        for metric, value in headline:
-            lines.append(f"| serve | mixed | mixed | {metric} | {value} |")
-        for phase, ms in sorted(
-                (serve.get("phase_means_ms") or {}).items()):
-            lines.append(f"| serve | mixed | mixed | phase mean ms: "
-                         f"{phase} | {_fmt(ms, '.3f')} |")
-    if federated:
-        S = federated.get("population")
-        for metric, value in [
-                (f"rounds/sec (S={S})",
-                 _fmt(federated.get("rounds_per_sec"), ".1f")),
-                (f"devices/sec (S={S})",
-                 _fmt(federated.get("devices_per_sec"))),
-                ("speedup vs scalar loop",
-                 _fmt(federated.get("speedup_vs_scalar"), ".1f")),
-                ("post-warmup traces",
-                 _fmt(federated.get("post_warmup_traces"))),
-        ]:
-            lines.append(
-                f"| federated | federated_corollary1 | dense "
-                f"| {metric} | {value} |")
-    return "\n".join(lines) + "\n"
-
-
-def trajectory(out=None):
-    text = trajectory_table()
-    if out:
-        open(out, "w").write(text)
-        print(f"trajectory table written to {out}")
-    else:
-        print(text, end="")
-
-
 def main(argv):
-    if argv and argv[0] == "trajectory":
-        trajectory(*argv[1:2])
-    elif argv and argv[0] == "roofline":
-        roofline(*argv[1:2])
-    else:
-        roofline(*argv[:1])
+    if argv and argv[0] == "roofline":
+        argv = argv[1:]
+    roofline(*argv[:1])
 
 
 if __name__ == "__main__":

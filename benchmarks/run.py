@@ -21,10 +21,6 @@ BENCHES = {
                       "beyond-paper: schedule on LLM pretraining"),
     "extensions": ("benchmarks.bench_extensions",
                    "paper Sec.-6 extensions: Th1 MC, noisy channel, multi-device"),
-    "fleet": ("benchmarks.bench_fleet",
-              "fleet engine: batched vs scalar-loop planning + cache hit-rate"),
-    "serve": ("benchmarks.bench_serve",
-              "always-on planning service: warmup, zero-trace SLO, latency"),
     "federated": ("benchmarks.bench_federated",
                   "federated round planner: joint selection + (rate, n_c)"),
     # roofline (reads dry-run artifacts)

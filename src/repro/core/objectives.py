@@ -453,8 +453,8 @@ class MonteCarloObjective:
     #: loss has no ceil(B_d)/B_d algebra driving the bound's tail teeth.
     #: stride 10 (vs the sqrt(G/2) default) widens the bracket: the
     #: empirical loss landscape is seed-noise-ragged near the optimum, and
-    #: the wider window recovers most of the raggedness at a lane cut
-    #: that still clears the >= 3x refinement floor in bench_fleet.
+    #: the wider window recovers most of the raggedness while the fine
+    #: pass still skips most of the dense pass's simulated lanes.
     #: The instance's seed schedule (``coarse_seeds`` / ``refine_rates``)
     #: folds in here, so the planner reads ONE hints object.
     @property

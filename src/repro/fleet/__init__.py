@@ -20,8 +20,10 @@ device-edge pair plannable; this package makes the FLEET the unit of work:
   * :class:`~repro.fleet.cache.PlanCache` — quantised-key LRU so repeated
     or near-identical requests skip the solve (keys carry the link's
     ``(model_id, params)`` signature AND the objective's cache token);
-  * ``repro.launch.plan_server`` — the micro-batching request-stream
-    driver reporting plans/sec (see ``python -m repro.launch.plan_server``).
+
+The request stream is served by :class:`repro.serve.PlanningService`
+(``python -m repro.launch.serve``), which micro-batches it through
+``FleetPlanner.plan_many``.
 """
 from repro.fleet.batch import ScenarioBatch
 from repro.fleet.bounds_jax import corollary1_bound_jax

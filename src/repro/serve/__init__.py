@@ -31,8 +31,7 @@ from repro.serve.resilience import (BREAKER_STATES, FALLBACK_LEVELS,
                                     RetryPolicy, SolveTimeEstimator)
 from repro.serve.service import PlanningService, ServiceConfig
 from repro.serve.sessions import Session, SessionTracker, reestimate_link
-from repro.serve.stats import (FederatedRecorder, ServiceStats,
-                               StatsRecorder, percentiles)
+from repro.serve.stats import FederatedRecorder, ServiceStats, StatsRecorder
 
 __all__ = [
     "ALL_MODELS", "ALL_OBJECTIVES", "AdmissionDecision",
@@ -47,7 +46,7 @@ __all__ = [
     "ServiceConfig", "ServiceStats", "Session", "SessionTracker",
     "SolveTimeEstimator", "StaticPolicy", "StatsRecorder",
     "default_consts", "export", "group_requests",
-    "mc_update_floor", "parse_models", "percentiles", "policy_spec",
+    "mc_update_floor", "parse_models", "policy_spec",
     "reestimate_link", "register_policy", "registered_policies",
     "resolve_grid_modes", "resolve_objectives", "synth_population",
     "synth_requests", "unregister_policy",

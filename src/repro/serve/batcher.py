@@ -93,8 +93,7 @@ class PlanRequest:
 
 def group_requests(items: List, key: Callable[[Any], Hashable]) -> List[List]:
     """Group ``items`` by ``key`` in first-seen order, preserving each
-    group's internal order — the canonical micro-batch grouping used by
-    both the always-on batcher and the one-shot ``plan_server`` driver."""
+    group's internal order — the micro-batcher's grouping of one flush."""
     groups: "OrderedDict[Hashable, List]" = OrderedDict()
     for it in items:
         groups.setdefault(key(it), []).append(it)

@@ -1,8 +1,7 @@
 """Shared serving catalogue: device classes, objectives, grid modes.
 
-Everything a planning front end needs to turn NAMES into planning
-configuration, used by both the always-on service
-(:mod:`repro.serve.service`) and the one-shot ``plan_server`` driver:
+Everything the planning service (:mod:`repro.serve.service`) and its
+launch drivers need to turn NAMES into planning configuration:
 
   * link factories for the synthetic device-class catalogue
     (:data:`LINK_FACTORIES` / :data:`ALL_MODELS`) and the heterogeneous

@@ -46,9 +46,8 @@ _by_objective_total``
 
 :func:`register_service_sources` wires a live
 :class:`~repro.serve.service.PlanningService` into its registry;
-:func:`oneshot_metrics` builds a standalone registry for the one-shot
-``plan_server`` driver; :func:`write_textfile` dumps any registry for
-the node-exporter textfile collector.
+:func:`write_textfile` dumps any registry for the node-exporter
+textfile collector.
 """
 from __future__ import annotations
 
@@ -384,45 +383,6 @@ def register_service_sources(registry: MetricsRegistry,
         "federated", lambda: federated_metrics(service.federated))
     registry.register_source(
         "resilience", lambda: resilience_metrics(service))
-
-
-def oneshot_metrics(stats, cache=None) -> MetricsRegistry:
-    """A standalone registry for the one-shot ``plan_server`` driver's
-    :class:`~repro.launch.plan_server.ServeStats` — same naming scheme,
-    ``repro_plan_server_`` prefix so a host running both exporters never
-    collides."""
-    def collect() -> List[Metric]:
-        out = [
-            Metric("repro_plan_server_requests_total", "counter",
-                   "requests served").add(float(stats.n_requests)),
-            Metric("repro_plan_server_batches_total", "counter",
-                   "micro-batches planned").add(float(stats.n_batches)),
-            Metric("repro_plan_server_seconds", "gauge",
-                   "serve loop wall clock").add(stats.seconds),
-            Metric("repro_plan_server_plans_per_sec", "gauge",
-                   "serve loop throughput").add(stats.plans_per_sec),
-            Metric("repro_plan_server_cache_hit_rate", "gauge",
-                   "stream cache hit rate").add(stats.cache_hit_rate),
-            Metric("repro_plan_server_batch_latency_p99_ms", "gauge",
-                   "per-micro-batch p99 latency").add(stats.batch_p99_ms),
-        ]
-        for label, per in (("model", stats.requests_per_model),
-                           ("objective", stats.requests_per_objective),
-                           ("grid_mode", stats.requests_per_grid_mode)):
-            if per:
-                m = Metric(f"repro_plan_server_requests_by_{label}_total",
-                           "counter", f"requests per {label}")
-                for k, v in sorted(per.items(), key=lambda kv: str(kv[0])):
-                    m.add(float(v), **{label: str(k)})
-                out.append(m)
-        if cache is not None:
-            out.extend(cache_metrics(cache.stats()))
-        out.extend(tracing_metrics())
-        return out
-
-    registry = MetricsRegistry()
-    registry.register_source("plan_server", collect)
-    return registry
 
 
 def write_textfile(registry: MetricsRegistry, path: str) -> str:

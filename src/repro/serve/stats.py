@@ -24,9 +24,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
-from repro.obs.hist import LogHistogram, percentiles  # noqa: F401
-# ``percentiles`` is re-exported: it moved to repro.obs.hist, and
-# callers (plan_server, benches) import it from here.
+from repro.obs.hist import LogHistogram
 
 BucketKey = Tuple[str, str, int]
 

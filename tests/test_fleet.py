@@ -1,8 +1,8 @@
 """Fleet planning engine: ScenarioBatch round-trips, batched == scalar plan
 equivalence (fixed cases + hypothesis property), the jnp bound port's
 lockstep with the numpy reference, PlanCache semantics, plan_many dedup,
-the micro-batching server, and the NamedSharding path (subprocess with a
-forced multi-device host platform)."""
+and the NamedSharding path (subprocess with a forced multi-device host
+platform)."""
 import os
 import subprocess
 import sys
@@ -17,8 +17,6 @@ from repro.core.bounds import corollary1_bound
 from repro.core.planner import fleet_grid
 from repro.fleet import (FleetPlanner, PlanCache, ScenarioBatch,
                          corollary1_bound_jax, scenario_key)
-from repro.launch.plan_server import (ALL_MODELS, default_consts, serve,
-                                      synth_requests)
 
 CONSTS = BoundConstants(L=1.908, c=0.061, M=1.0, M_G=1.0, D=1.0, alpha=1e-4)
 RATES5 = (1.0, 1.25, 1.5, 2.0, 3.0)
@@ -423,72 +421,6 @@ def test_boundary_clamps_to_inf_at_deadline_equal_dataset():
 
 
 # ---------------------------------------------------------------------------
-# plan server
-# ---------------------------------------------------------------------------
-
-
-def test_serve_micro_batches_request_stream():
-    requests = synth_requests(96, seed=5, dup_frac=0.5)
-    assert len(requests) == 96
-    cache = PlanCache(maxsize=256)
-    stats = serve(requests, planner=FleetPlanner(grid_size=16),
-                  consts=default_consts(), cache=cache, batch_size=32)
-    assert stats.n_requests == 96 and stats.n_batches == 3
-    assert len(stats.records) == 96
-    assert stats.plans_per_sec > 0
-    assert 0.0 < stats.cache_hit_rate < 1.0
-    assert stats.requests_per_model == {ErasureLink.model_id: 96}
-    for rec in stats.records:
-        assert rec.n_c >= 1 and np.isfinite(rec.bound_value)
-        assert rec.rate in RATES5
-    with pytest.raises(ValueError):
-        serve(requests, planner=FleetPlanner(), consts=default_consts(),
-              batch_size=0)
-
-
-def test_serve_mixed_model_stream_one_kernel():
-    """A stream mixing EVERY registered channel family is served through
-    the same micro-batch loop: each record matches its scalar solve and
-    the per-model counts cover all four families."""
-    requests = synth_requests(48, seed=7, dup_frac=0.3, models=ALL_MODELS)
-    stats = serve(requests, planner=FleetPlanner(grid_size=16),
-                  consts=default_consts(), cache=PlanCache(maxsize=256),
-                  batch_size=16)
-    assert len(stats.records) == 48
-    assert sum(stats.requests_per_model.values()) == 48
-    assert set(stats.requests_per_model) == {
-        IdealLink.model_id, ErasureLink.model_id, FadingLink.model_id,
-        GilbertElliottLink.model_id}
-    for sc, rec in zip(requests, stats.records):
-        _assert_record_matches_scalar(sc, rec.n_c, rec.rate,
-                                      rec.bound_value, default_consts(), 16)
-
-
-def test_serve_empty_and_fully_cached_streams_report_finite_stats():
-    """Regression: hit-rate / throughput reporting must stay finite (no
-    0/0 NaN) on an empty stream, and a fully-cached replay reports a 1.0
-    PER-STREAM hit rate (counter deltas, not cache lifetime totals)."""
-    planner = FleetPlanner(grid_size=16)
-    cache = PlanCache(maxsize=64)
-    empty = serve([], planner=planner, consts=default_consts(), cache=cache,
-                  batch_size=8)
-    assert empty.n_requests == 0 and empty.n_batches == 0
-    assert empty.records == [] and empty.requests_per_model == {}
-    assert empty.cache_hit_rate == 0.0 and np.isfinite(empty.plans_per_sec)
-    # no-cache path is equally well-defined on an empty stream
-    nocache = serve([], planner=planner, consts=default_consts(), cache=None)
-    assert nocache.cache_hit_rate == 0.0
-
-    requests = synth_requests(24, seed=9, dup_frac=0.0, models=ALL_MODELS)
-    first = serve(requests, planner=planner, consts=default_consts(),
-                  cache=cache, batch_size=8)
-    replay = serve(requests, planner=planner, consts=default_consts(),
-                   cache=cache, batch_size=8)
-    assert replay.cache_hit_rate == 1.0      # lifetime rate would be ~0.5
-    assert [r.n_c for r in replay.records] == [r.n_c for r in first.records]
-
-
-# ---------------------------------------------------------------------------
 # registry plugin: a custom channel goes end-to-end in ~30 lines
 # ---------------------------------------------------------------------------
 
@@ -570,7 +502,7 @@ import numpy as np, jax
 assert jax.device_count() == 4, jax.devices()
 from repro.core import BoundConstants
 from repro.fleet import FleetPlanner, ScenarioBatch
-from repro.launch.plan_server import ALL_MODELS, default_consts, synth_requests
+from repro.serve import ALL_MODELS, default_consts, synth_requests
 scs = synth_requests(8, seed=3, dup_frac=0.0, models=ALL_MODELS)
 batch = ScenarioBatch.from_scenarios(scs)
 sharded = FleetPlanner(grid_size=16, shard=True).plan_batch(batch, default_consts())
@@ -608,7 +540,7 @@ assert jax.device_count() == 4, jax.devices()
 from repro.core.objectives import MonteCarloObjective
 from repro.core.scenario import ErasureLink, Scenario
 from repro.fleet import FleetPlanner, ScenarioBatch
-from repro.launch.plan_server import default_consts
+from repro.serve import default_consts
 rng = np.random.default_rng(0)
 X = rng.normal(size=(48, 4))
 y = X @ rng.normal(size=4) + 0.1 * rng.normal(size=48)

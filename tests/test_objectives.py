@@ -3,8 +3,7 @@ BoundObjective extraction (bitwise-identical plans), the exact burst-aware
 MarkovARQObjective (reduction + strictly-better sticky plans, scalar and
 fleet), the batched MonteCarloObjective (seed-for-seed equal to the scalar
 planner, fixed cases + hypothesis property), objective-scoped PlanCache
-keys, the mixed-objective plan server, a custom-objective plugin going
-end-to-end, and the unknown-objective CLI exit code."""
+keys, and a custom-objective plugin going end-to-end."""
 from dataclasses import dataclass
 from typing import ClassVar
 
@@ -23,8 +22,7 @@ from repro.fleet import (FleetPlanner, PlanCache, ScenarioBatch,
                          grid_objective_builder, objective_token,
                          register_objective_kernel,
                          unregister_objective_kernel)
-from repro.launch.plan_server import (default_consts, resolve_objectives,
-                                      serve, synth_requests)
+from repro.serve import resolve_objectives
 
 CONSTS = BoundConstants(L=1.908, c=0.061, M=1.0, M_G=1.0, D=1.0, alpha=1e-4)
 RATES5 = (1.0, 1.25, 1.5, 2.0, 3.0)
@@ -422,33 +420,8 @@ def test_cache_objective_scoping_on_sticky_chain_records_differ():
 
 
 # ---------------------------------------------------------------------------
-# plan server: mixed-objective streams
+# objective names
 # ---------------------------------------------------------------------------
-
-
-def test_serve_mixed_objective_stream():
-    requests = synth_requests(48, seed=7, dup_frac=0.3, n_max=2048)
-    catalogue = resolve_objectives(("corollary1", "markov_arq"))
-    instances = list(catalogue.values())
-    objectives = [instances[i % 2] for i in range(len(requests))]
-    stats = serve(requests, planner=FleetPlanner(grid_size=16),
-                  consts=default_consts(), cache=PlanCache(maxsize=256),
-                  batch_size=16, objectives=objectives)
-    assert len(stats.records) == 48
-    assert stats.requests_per_objective == {"corollary1": 24,
-                                            "markov_arq": 24}
-    for i, (rec, obj) in enumerate(zip(stats.records, objectives)):
-        assert rec.objective == obj.objective_id
-        assert rec.n_c >= 1 and np.isfinite(rec.bound_value)
-        sp = ObjectivePlanner(objective=obj,
-                              grid=fleet_grid(requests[i].N, 16)
-                              ).plan(requests[i], default_consts())
-        assert (rec.n_c, rec.rate) == (sp.n_c, sp.rate) \
-            or abs(rec.bound_value - sp.bound_value) \
-            <= 1e-9 * abs(sp.bound_value)
-    with pytest.raises(ValueError, match="one per request"):
-        serve(requests, planner=FleetPlanner(), consts=default_consts(),
-              objectives=[instances[0]])
 
 
 def test_resolve_objectives_unknown_and_empty():
@@ -458,15 +431,6 @@ def test_resolve_objectives_unknown_and_empty():
         resolve_objectives(())
     assert set(resolve_objectives("all")) == {"corollary1", "markov_arq",
                                               "montecarlo"}
-
-
-def test_plan_server_cli_unknown_objective_exit_code():
-    """ISSUE satellite: requesting an unregistered objective exits with a
-    non-zero status and a clear error (matches the unknown-bench
-    behaviour of benchmarks.run)."""
-    from repro.launch import plan_server
-
-    assert plan_server.main(["--objective", "nope", "--requests", "1"]) == 2
 
 
 # ---------------------------------------------------------------------------

@@ -202,7 +202,7 @@ from repro.core.objectives import BoundObjective, MarkovARQObjective
 from repro.fleet import FleetPlanner, ScenarioBatch
 from repro.fleet import objective_kernels as ok
 from repro.core.planner import fleet_grid
-from repro.launch.plan_server import ALL_MODELS, default_consts, synth_requests
+from repro.serve import ALL_MODELS, default_consts, synth_requests
 scs = synth_requests(16, seed=4, dup_frac=0.0, models=ALL_MODELS)
 batch = ScenarioBatch.from_scenarios(scs)
 consts = default_consts()

@@ -3,7 +3,7 @@ coarse_indices / refine_window_bounds), fixed-case argmin parity of the
 refined vs dense solves for all three shipped objectives, the hypothesis
 refinement-parity property (subset invariants + tail-guard exactness +
 rate-major tie-breaking) over mixed link-model batches, dense fallbacks,
-and the grid-mode plumbing (cache scoping, serving stats, CLI exits)."""
+and the grid-mode plumbing (validation, cache scoping)."""
 import numpy as np
 import pytest
 
@@ -17,8 +17,7 @@ from repro.core.scenario import (ErasureLink, FadingLink, GilbertElliottLink,
                                  IdealLink, MultiDevice, Scenario,
                                  SingleDevice)
 from repro.fleet import GRID_MODES, FleetPlanner, PlanCache, ScenarioBatch
-from repro.launch.plan_server import (default_consts, resolve_grid_modes,
-                                      serve, synth_requests)
+from repro.serve import resolve_grid_modes
 
 CONSTS = BoundConstants(L=1.908, c=0.061, M=1.0, M_G=1.0, D=1.0, alpha=1e-4)
 RATES5 = (1.0, 1.25, 1.5, 2.0, 3.0)
@@ -347,7 +346,7 @@ else:  # surface the missing property coverage as skips, not silence
 
 
 # ---------------------------------------------------------------------------
-# grid-mode plumbing: caching, serving, CLI
+# grid-mode plumbing: validation, caching
 # ---------------------------------------------------------------------------
 
 
@@ -383,34 +382,3 @@ def test_cache_scoped_by_grid_mode():
     assert planner_d.plan_many(scs, CONSTS, cache=cache,
                                grid_mode="refine") == rec_r
     assert len(cache) == 4
-
-
-def test_serve_mixed_grid_mode_stream():
-    requests = synth_requests(32, seed=13, dup_frac=0.0)
-    modes = ["refine" if i % 2 else "dense" for i in range(32)]
-    stats = serve(requests, planner=FleetPlanner(grid_size=16),
-                  consts=default_consts(), cache=PlanCache(maxsize=64),
-                  batch_size=16, grid_modes=modes)
-    assert stats.requests_per_grid_mode == {"dense": 16, "refine": 16}
-    assert all(rec is not None for rec in stats.records)
-    # mode list must be per-request
-    with pytest.raises(ValueError):
-        serve(requests, planner=FleetPlanner(grid_size=16),
-              consts=default_consts(), grid_modes=["dense"])
-    # unknown mode names are rejected, not silently remapped
-    with pytest.raises(ValueError):
-        serve(requests, planner=FleetPlanner(grid_size=16),
-              consts=default_consts(), grid_modes=["dense"] * 31 + ["x"])
-
-
-def test_plan_server_cli_unknown_grid_mode_exits_2():
-    from repro.launch.plan_server import main
-    assert main(["--requests", "4", "--grid-mode", "bogus"]) == 2
-
-
-def test_plan_server_cli_mixed_modes_smoke(capsys):
-    from repro.launch.plan_server import main
-    assert main(["--requests", "24", "--batch", "8", "--grid", "8",
-                 "--grid-mode", "all", "--n-max", "2048"]) == 0
-    out = capsys.readouterr().out
-    assert "grid-mode mix:" in out and "dense=" in out and "refine=" in out

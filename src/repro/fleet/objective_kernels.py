@@ -118,8 +118,10 @@ def fleet_solve(objective) -> Callable:
 
 
 def _count_in(n_in: int) -> None:
-    """One jitted call with ``n_in`` arguments copied in, on the open
-    chunk record."""
+    """One jitted call with ``n_in`` host-to-device copies, on the open
+    chunk record.  A copy is one array however many devices it is laid
+    over: the grid solves copy each argument, the Monte-Carlo solve its
+    one packed buffer."""
     count("dispatches")
     count("h2d_arrays", n_in)
 
@@ -128,10 +130,8 @@ def _fetch(out: dict) -> dict:
     """Wait on a jitted call's results and copy them back to host
     arrays, as two leaves: one transfer per array.
 
-    The Monte-Carlo solve and the federated round fetch this way: their
-    outputs differ from the grid solves', so they keep the plain
-    per-array path.  The grid solves pack their outputs into one buffer
-    (:func:`_fetch_packed`)."""
+    The federated round fetches this way.  The grid and Monte-Carlo
+    solves pack their outputs into one buffer (:func:`_fetch_packed`)."""
     with span("planner.device_wait"):
         jax.block_until_ready(out)
     with span("planner.fetch"):
@@ -140,48 +140,63 @@ def _fetch(out: dict) -> dict:
     return res
 
 
-def _pack(out: dict):
-    """Every ``(S, ...)`` output as float64 columns of one ``(S, K)``
+def _pack(out: dict, xp=jnp):
+    """Every ``(S, ...)`` array as float64 columns of one ``(S, K)``
     buffer, and the static layout ``((key, start, stop, dtype, trailing
-    shape), ...)`` that :func:`_fetch_packed` reads it back with.
+    shape), ...)`` that :func:`_unpack` cuts it back with.
 
-    Called while tracing.  Integer and bool outputs round-trip exactly:
-    served block sizes (at most the service's ``n_max``) and indices
-    (below the grid width) are below 2**24, exact even in the TPU's
-    emulated float64.  Concatenating along axis 1 keeps a
-    scenario-sharded batch sharded."""
+    With ``xp=jnp`` it runs while tracing, on a solve's outputs; with
+    ``xp=np`` on the host, on a solve's arguments.  Integers and bools
+    round-trip exactly: block sizes (at most the service's ``n_max``),
+    link-family ids and indices (below the grid width) are below 2**24,
+    exact even in the TPU's emulated float64.  Concatenating along axis 1
+    keeps a scenario-sharded batch sharded."""
     S = next(iter(out.values())).shape[0]
     cols, layout, start = [], [], 0
     for key, v in out.items():
         trailing = tuple(v.shape[1:])
         width = math.prod(trailing)
-        cols.append(v.reshape(S, width).astype(jnp.float64))
+        cols.append(v.reshape(S, width).astype(np.float64))
         layout.append((key, start, start + width, np.dtype(v.dtype),
                        trailing))
         start += width
-    return jnp.concatenate(cols, axis=1), tuple(layout)
+    return xp.concatenate(cols, axis=1), tuple(layout)
 
 
-def _fetch_packed(buf, layout) -> dict:
+def _unpack(buf, layout) -> dict:
+    """The dict :func:`_pack` packed: each column block cast back to its
+    dtype and trailing shape (host or traced arrays alike)."""
+    return {key: buf[:, a:b].astype(dtype).reshape((-1,) + trailing)
+            for key, a, b, dtype, trailing in layout}
+
+
+def _fetch_packed(buf, layout, rows=None) -> dict:
     """:func:`_fetch` for a packed solve: wait, then ONE copy back, cut
-    into the same dict of host arrays (keys, dtypes, shapes, values)."""
+    into the same dict of host arrays (keys, dtypes, shapes, values).
+    ``rows``, where given, reorders the fetched rows before the cut."""
     with span("planner.device_wait"):
         buf.block_until_ready()
     with span("planner.fetch"):
         host = np.asarray(buf)
         count("d2h_arrays")
-        res = {key: host[:, a:b].astype(dtype).reshape((-1,) + trailing)
-               for key, a, b, dtype, trailing in layout}
+        if rows is not None:
+            host = host[rows]
+        res = _unpack(host, layout)
     return res
+
+
+def _fleet_sharding() -> NamedSharding:
+    """The scenario axis laid over every local device."""
+    mesh = Mesh(np.asarray(jax.local_devices()), ("fleet",))
+    return NamedSharding(mesh, P("fleet"))
 
 
 def _maybe_shard(arrays: dict, S: int) -> dict:
     """Lay the batch out across local devices over the scenario axis."""
-    devices = jax.local_devices()
-    if len(devices) <= 1 or S % len(devices) != 0:
+    n_dev = len(jax.local_devices())
+    if n_dev <= 1 or S % n_dev != 0:
         return arrays
-    mesh = Mesh(np.asarray(devices), ("fleet",))
-    sharding = NamedSharding(mesh, P("fleet"))
+    sharding = _fleet_sharding()
     return {k: jax.device_put(v, sharding) for k, v in arrays.items()}
 
 
@@ -483,7 +498,14 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
     """Jitted Monte-Carlo solve for one objective instance (its data and
     hyperparameters — including ``crn`` and ``seed_stream`` — are
     compile-time constants) and link-table version.  ``interpret`` runs
-    the pallas engine through the Pallas interpreter (the CPU path)."""
+    the pallas engine through the Pallas interpreter (the CPU path).
+
+    Returns ``(montecarlo_solve, layout)``.  The solve takes its nine
+    arguments as one float64 ``(S, K)`` buffer and the static layout
+    :func:`_mc_args` packed it with, and returns its outputs as one
+    float64 buffer, as the grid solves do (:func:`_build_grid_solve`);
+    ``layout(args_layout)`` gives that buffer's layout, recorded while
+    tracing.  The device module is ``jit_montecarlo_solve``."""
     del link_version  # cache key only
     branches = kernel_table()
     # float32 mirrors the scalar path, which runs OUTSIDE enable_x64 and
@@ -510,11 +532,20 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
             return jax.random.PRNGKey(seed0 + 97 * r)
         return jax.random.fold_in(jax.random.PRNGKey(seed0), r)
 
-    @partial(jax.jit, static_argnames=("max_updates", "shard_lanes",
-                                       "mc_impl", "mc_seeds"))
-    def montecarlo_solve(N, T, union_no, tau_p, rates, rate_mask, grid,
-               link_model_id, link_params, *, max_updates,
-               shard_lanes=False, mc_impl="scan", mc_seeds=None):
+    layouts = {}
+
+    @partial(jax.jit, static_argnames=("layout", "max_updates",
+                                       "shard_lanes", "mc_impl", "mc_seeds"))
+    def montecarlo_solve(buf, *, layout, max_updates, shard_lanes=False,
+                         mc_impl="scan", mc_seeds=None):
+        out, layouts[layout] = _pack(solve_arrays(
+            **_unpack(buf, layout), max_updates=max_updates,
+            shard_lanes=shard_lanes, mc_impl=mc_impl, mc_seeds=mc_seeds))
+        return out
+
+    def solve_arrays(N, T, union_no, tau_p, rates, rate_mask, grid,
+                     link_model_id, link_params, *, max_updates,
+                     shard_lanes, mc_impl, mc_seeds):
         runs = int(mc_seeds) if mc_seeds else n_runs
         record_trace(("montecarlo", mc_impl, crn, runs)
                      + tuple(grid.shape) + (max_updates,))
@@ -748,7 +779,19 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
         return _reduce_joint_argmin(vals, n_o_eff, p, N, T, rates,
                                     rate_mask, grid)
 
-    return montecarlo_solve
+    return montecarlo_solve, layouts.__getitem__
+
+
+def _mc_args(arrays: dict):
+    """The Monte-Carlo solve's arguments as one float64 ``(S, K)`` host
+    matrix and its static layout (:func:`_pack`): one column block per
+    key in the batch's key order (``N``, ``T``, ``union_no``, ``tau_p``,
+    ``rates`` (R), ``rate_mask`` (R), ``grid`` (G, or R x W on a per-rate
+    grid), ``link_model_id``, ``link_params`` (P)), so the layout is fixed
+    by (R, G, P) and the dtypes.  The float64 columns are the arrays'
+    own values and every integer and bool is below 2**24, so the solve
+    cuts back bitwise the arrays it would be given one by one."""
+    return _pack({k: np.asarray(v) for k, v in arrays.items()}, np)
 
 
 def _mc_horizons(arrays: dict, max_updates: int) -> np.ndarray:
@@ -786,7 +829,7 @@ def _mc_run_slots(horizon: np.ndarray, per_scenario: int,
 
 
 def _count_mc(arrays: dict, runs: int, max_updates: int, sharded: bool,
-              kernel_devices: int = 0) -> None:
+              kernel_devices: int = 0, order=None) -> None:
     """One Monte-Carlo pass on the open chunk record, reckoned on the host
     from the batch's arrays.  A lane is one simulated trajectory (run x
     scenario x rate x grid point, bucket padding included):
@@ -796,7 +839,8 @@ def _count_mc(arrays: dict, runs: int, max_updates: int, sharded: bool,
     lane's first block arrives are live but masked), and
     ``mc_run_slots`` the slots the pallas kernel steps each lane through
     (its block's longest deadline), over ``kernel_devices`` devices in
-    the arrays' order; the scan engines run no kernel and count none.
+    the arrays' order, or in ``order`` where the kernel is fed the rows
+    so; the scan engines run no kernel and count none.
     ``mc_sharded_dispatches`` counts a pass laid over every local
     device."""
     per_scenario = arrays["rates"].shape[1] * arrays["grid"].shape[-1]
@@ -805,7 +849,8 @@ def _count_mc(arrays: dict, runs: int, max_updates: int, sharded: bool,
           runs * per_scenario * horizon.shape[0] * max_updates)
     count("mc_live_slots", runs * per_scenario * int(horizon.sum()))
     if kernel_devices:
-        count("mc_run_slots", runs * _mc_run_slots(horizon, per_scenario,
+        fed = horizon if order is None else horizon[order]
+        count("mc_run_slots", runs * _mc_run_slots(fed, per_scenario,
                                                    kernel_devices))
     if sharded:
         count("mc_sharded_dispatches")
@@ -816,19 +861,27 @@ def montecarlo_builder(objective) -> Callable:
     timeline to the next power of two over the batch (masked slots no-op,
     so plans are unaffected) to bound how many scan lengths can compile.
 
-    Sharded like the grid solves: the batch arrays are laid out over the
-    local devices' "fleet" mesh on the scenario axis via ``_maybe_shard``,
-    and the kernel constrains its flattened scenario-major ``(S * R * G)``
-    simulation-lane axis to the same mesh, so every device simulates its
-    own scenarios' lanes.  Requires both ``S`` and the lane count to
-    divide the device count; otherwise the solve runs unsharded (single
-    device is the common case and is bitwise-unchanged by this path).
+    Each solve crosses the host-device boundary once each way: its nine
+    argument arrays travel as one float64 ``(S, K)`` matrix
+    (:func:`_mc_args`), one ``device_put``, and its outputs come back as
+    one buffer (:func:`_fetch_packed`), cut into the dict of host arrays
+    the per-array path gave (same keys, dtypes, shapes and bits).  The
+    chunk record counts one ``h2d_arrays`` and one ``d2h_arrays``.
+
+    Sharded like the grid solves: the matrix is laid out over the local
+    devices' "fleet" mesh on the scenario axis, and the kernel constrains
+    its flattened scenario-major ``(S * R * G)`` simulation-lane axis to
+    the same mesh, so every device simulates its own scenarios' lanes.
+    Requires both ``S`` and the lane count to divide the device count;
+    otherwise the solve runs unsharded (single device is the common case
+    and is bitwise-unchanged by this path).
 
     The pallas engine gets the scenarios in deadline order
-    (:func:`_mc_order`), so its lane blocks stop early together, and its
-    outputs are put back in the caller's order.  Lanes are independent
-    (one shared uniform per slot, lane-wise losses), so the order changes
-    no plan.
+    (:func:`_mc_order`), one row index of the packed matrix, so its lane
+    blocks stop early together; one row index of the fetched matrix puts
+    the outputs back in the caller's order.  Lanes are independent (one
+    shared uniform per slot, lane-wise losses), so the order changes no
+    plan.
     """
 
     def solve(arrays, consts, shard, batch):
@@ -842,8 +895,8 @@ def montecarlo_builder(objective) -> Callable:
         mc_updates = arrays.pop("mc_updates", None)
         # the pallas engine runs interpreted on the CPU (the test suite)
         # and compiled everywhere else
-        fn = _mc_solve_for(objective, kernel_table_version(),
-                           jax.default_backend() == "cpu")
+        fn, out_layout = _mc_solve_for(objective, kernel_table_version(),
+                                       jax.default_backend() == "cpu")
         # the objective's min_updates floor pins the padded scan length
         # for serving: every batch below the floor shares ONE shape
         # (padded slots no-op, so plans are unaffected)
@@ -863,31 +916,26 @@ def montecarlo_builder(objective) -> Callable:
         shard = bool(shard) and n_dev > 1 and S % n_dev == 0 \
             and lanes % n_dev == 0
         kernel_devices = 0
-        order = None
+        order = back = None
         with span("planner.dispatch"):
+            host, layout = _mc_args(arrays)
             if mc_impl == "pallas":
                 kernel_devices = n_dev if shard else 1
                 order = _mc_order(_mc_horizons(arrays, max_updates),
                                   kernel_devices)
-                arrays = {k: np.asarray(v)[order]
-                          for k, v in arrays.items()}
-            _count_in(len(arrays))
+                back = np.argsort(order)
+                host = host[order]
+            _count_in(1)
             _count_mc(arrays, int(mc_seeds or objective.n_runs),
-                      max_updates, shard, kernel_devices)
+                      max_updates, shard, kernel_devices, order)
             with jax.enable_x64(True):
-                if shard:
-                    arrays = _maybe_shard(arrays, S)
-                out = fn(max_updates=max_updates, shard_lanes=shard,
-                         mc_impl=str(mc_impl),
+                buf = jax.device_put(host, _fleet_sharding() if shard
+                                     else None)
+                out = fn(buf, layout=layout, max_updates=max_updates,
+                         shard_lanes=shard, mc_impl=str(mc_impl),
                          mc_seeds=None if mc_seeds is None
-                         else int(mc_seeds), **arrays)
-        res = _fetch(out)
-        if order is not None:
-            with span("planner.fetch"):
-                back = np.empty_like(order)
-                back[order] = np.arange(S)
-                res = {k: v[back] for k, v in res.items()}
-        return res
+                         else int(mc_seeds))
+        return _fetch_packed(out, out_layout(layout), back)
 
     solve.supports_mc_impl = True
     return solve

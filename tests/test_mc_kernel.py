@@ -175,7 +175,8 @@ def _solve_three_ways(mc, scs, pad_to):
     block bounded), the jitted pallas solve on the caller's order, and the
     CRN scan engine, which steps every lane through the whole horizon."""
     from repro.fleet.link_kernels import kernel_table_version
-    from repro.fleet.objective_kernels import _mc_solve_for, pow2ceil
+    from repro.fleet.objective_kernels import (_mc_args, _mc_solve_for,
+                                               _unpack, pow2ceil)
     from repro.fleet.planner import _pad_batch
     batch = ScenarioBatch.from_scenarios(_pad_batch(scs, pad_to))
     grid = fleet_grid(batch.N, 6)
@@ -183,11 +184,12 @@ def _solve_three_ways(mc, scs, pad_to):
     solve = fleet_solve(mc)
     served = solve(dict(arrays, mc_impl="pallas"), CONSTS, False, batch)
     scan = solve(dict(arrays), CONSTS, False, batch)
+    fn, layout = _mc_solve_for(mc, kernel_table_version(), True)
+    host, args = _mc_args(arrays)
     with jax.enable_x64(True):
-        direct = _mc_solve_for(mc, kernel_table_version(), True)(
-            max_updates=pow2ceil(batch.max_updates), mc_impl="pallas",
-            **arrays)
-    return served, jax.device_get(direct), scan, batch
+        buf = fn(host, layout=args, max_updates=pow2ceil(batch.max_updates),
+                 mc_impl="pallas")
+    return served, _unpack(np.asarray(buf), layout(args)), scan, batch
 
 
 @pytest.mark.parametrize("crn", [False, True])

@@ -367,7 +367,9 @@ def test_montecarlo_chunk_counts_lane_slots_and_sharded_passes(devices):
     """Each Monte-Carlo pass counts its lanes' padded slots and the slots
     before each lane's deadline (pad lanes repeat the smallest scenario),
     and counts a sharded dispatch only where the batch splits evenly over
-    every device: never on one device, nor for a batch of 6 on four."""
+    every device: never on one device, nor for a batch of 6 on four.
+    Each pass copies its arguments in as one packed buffer and its
+    results back as one."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=os.pathsep.join(
@@ -390,6 +392,7 @@ def test_montecarlo_chunk_counts_lane_slots_and_sharded_passes(devices):
         total = [int(np.floor(1.3 * N[i] / tau[i])) for i in rows]
         horizon = pow2ceil(max(total))
         assert counts["dispatches"] == 1
+        assert counts["h2d_arrays"] == counts["d2h_arrays"] == 1, key
         assert counts["mc_lane_slots"] == 2 * per_scenario * pad_to * horizon
         assert counts["mc_live_slots"] == 2 * per_scenario * sum(
             min(t, horizon) for t in total)
@@ -445,7 +448,8 @@ def test_montecarlo_run_slots_step_each_block_to_its_longest_deadline():
 def test_montecarlo_pallas_pass_counts_run_slots(devices):
     """A served pallas pass counts its run slots: 16 lanes a scenario, so
     each device's lanes fill at most one block, which steps to the
-    longest deadline the device holds after the deal by deadline."""
+    longest deadline the device holds after the deal by deadline; the
+    pass copies in and back once."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=os.pathsep.join(
@@ -473,3 +477,4 @@ def test_montecarlo_pallas_pass_counts_run_slots(devices):
         assert counts["mc_run_slots"] == 2 * 16 * per_part * sum(longest)
         assert counts["mc_live_slots"] <= counts["mc_run_slots"] \
             <= counts["mc_lane_slots"]
+        assert counts["h2d_arrays"] == counts["d2h_arrays"] == 1, key

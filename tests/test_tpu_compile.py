@@ -100,18 +100,19 @@ def test_montecarlo_solve_compiles_with_pallas(one_chip):
     64-request bucket over every link family, a 32-point grid, the
     16,384-slot timeline of ``n_max=2048`` serving, CRN tables."""
     from repro.fleet.link_kernels import kernel_table_version
-    from repro.fleet.objective_kernels import _mc_solve_for
+    from repro.fleet.objective_kernels import _mc_args, _mc_solve_for
     from repro.serve.catalogue import make_montecarlo_objective, \
         mc_update_floor
     objective = make_montecarlo_objective(mc_update_floor(2048), crn=True)
     batch = ScenarioBatch.from_scenarios(
         synth_requests(64, seed=1, models=ALL_MODELS, n_max=2048))
     arrays = FleetPlanner._solve_arrays(batch, fleet_grid(batch.N, 32))
-    solve = _mc_solve_for(objective, kernel_table_version(), False)
+    solve, _ = _mc_solve_for(objective, kernel_table_version(), False)
+    host, layout = _mc_args(arrays)
     with jax.enable_x64(True):
-        compiled = solve.lower(max_updates=mc_update_floor(2048),
-                               mc_impl="pallas",
-                               **_shapes(one_chip, arrays)).compile()
+        compiled = solve.lower(_shapes(one_chip, host), layout=layout,
+                               max_updates=mc_update_floor(2048),
+                               mc_impl="pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -193,7 +194,7 @@ def test_sharded_montecarlo_solve_compiles_on_four_chips(one_chip, topo,
     pointed here at the described chips."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.fleet.link_kernels import kernel_table_version
-    from repro.fleet.objective_kernels import _mc_solve_for
+    from repro.fleet.objective_kernels import _mc_args, _mc_solve_for
     from repro.serve.catalogue import make_montecarlo_objective, \
         mc_update_floor
     del one_chip  # its fixture keeps the persistent cache off
@@ -203,10 +204,11 @@ def test_sharded_montecarlo_solve_compiles_on_four_chips(one_chip, topo,
     batch = ScenarioBatch.from_scenarios(
         synth_requests(64, seed=1, models=ALL_MODELS, n_max=2048))
     arrays = FleetPlanner._solve_arrays(batch, fleet_grid(batch.N, 32))
-    solve = _mc_solve_for(objective, kernel_table_version(), False)
+    solve, _ = _mc_solve_for(objective, kernel_table_version(), False)
+    host, layout = _mc_args(arrays)
     monkeypatch.setattr(jax, "local_devices", lambda: list(topo.devices))
     with jax.enable_x64(True):
-        compiled = solve.lower(max_updates=mc_update_floor(2048),
-                               shard_lanes=True, mc_impl="pallas",
-                               **_shapes(fleet, arrays)).compile()
+        compiled = solve.lower(_shapes(fleet, host), layout=layout,
+                               max_updates=mc_update_floor(2048),
+                               shard_lanes=True, mc_impl="pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()

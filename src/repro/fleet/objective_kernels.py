@@ -68,7 +68,7 @@ from repro.core.pipeline import ridge_dot, ridge_grad_sample, ridge_losses
 from repro.fleet.bounds_jax import corollary1_bound_jax
 from repro.fleet.link_kernels import kernel_table, kernel_table_version
 from repro.fleet.tracing import record_trace
-from repro.obs.runtime import count, span
+from repro.obs.runtime import count, note, span
 
 _BUILDERS: Dict[str, Callable] = {}
 _VERSION = 0
@@ -645,7 +645,7 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
             W, _ = jax.lax.scan(inner, W, (ix, m), unroll=2)
             return W
 
-        def pallas_slab(W, Xs, ys, ix, m, j0):
+        def pallas_slab(W, rows, ix, m, j0):
             from repro.kernels.mc_ridge import mc_ridge_slab
             slab_fn = partial(mc_ridge_slab, alpha=alpha, lam=lam,
                               fused=crn, interpret=interpret,
@@ -655,10 +655,10 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
                 # it on its own lanes (lanes are independent)
                 slab_fn = jax.shard_map(
                     slab_fn, mesh=mesh,
-                    in_specs=(P("fleet"), P(), P(), P(None, "fleet"),
+                    in_specs=(P("fleet"), P(), P(None, "fleet"),
                               P(None, "fleet"), P("fleet"), P()),
                     out_specs=P("fleet"), check_vma=False)
-            return slab_fn(W, Xs, ys, ix, m, lane_hi, j0)
+            return slab_fn(W, rows, ix, m, lane_hi, j0)
 
         def per_run_exact_scan(r):
             # the reference engine: per-slot split + randint INSIDE the
@@ -699,10 +699,12 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
             # past every deadline would only mask, so neither their
             # tables nor their calls are made; the uniforms are still
             # drawn for the whole horizon, so the stream is unchanged
+            from repro.kernels.mc_ridge import row_table
             key = run_key(r)
             kp, kw, ks = jax.random.split(key, 3)
             perm = jax.random.permutation(kp, n)
-            Xs, ys = X[perm], y[perm]
+            # the run's permuted rows as the kernel's table, once a run
+            rows = row_table(X[perm], y[perm])
             w0 = jax.random.normal(kw, (d,), jnp.float32)
             W0 = jnp.broadcast_to(w0, (L, d))
 
@@ -712,7 +714,7 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
 
                 def outer(s, W):
                     ix, m = crn_tables(s * slab, u[s])
-                    return pallas_slab(W, Xs, ys, ix, m, s * slab)
+                    return pallas_slab(W, rows, ix, m, s * slab)
 
                 return jax.lax.fori_loop(np.int32(0), live_slabs, outer,
                                          W0)
@@ -720,7 +722,7 @@ def _mc_solve_for(objective, link_version: int, interpret: bool):
             def outer(s, carry):
                 W, k = carry
                 k, (ix, m) = exact_tables(k, s * slab)
-                return pallas_slab(W, Xs, ys, ix, m, s * slab), k
+                return pallas_slab(W, rows, ix, m, s * slab), k
 
             W_fin, _ = jax.lax.fori_loop(np.int32(0), live_slabs, outer,
                                          (W0, ks))
@@ -866,7 +868,9 @@ def montecarlo_builder(objective) -> Callable:
     (:func:`_mc_args`), one ``device_put``, and its outputs come back as
     one buffer (:func:`_fetch_packed`), cut into the dict of host arrays
     the per-array path gave (same keys, dtypes, shapes and bits).  The
-    chunk record counts one ``h2d_arrays`` and one ``d2h_arrays``.
+    chunk record counts one ``h2d_arrays`` and one ``d2h_arrays``, and
+    notes the data set's rows as ``mc_rows``: the pallas engine's row
+    gather depends on them (:mod:`repro.kernels.mc_ridge`).
 
     Sharded like the grid solves: the matrix is laid out over the local
     devices' "fleet" mesh on the scenario axis, and the kernel constrains
@@ -928,6 +932,7 @@ def montecarlo_builder(objective) -> Callable:
             _count_in(1)
             _count_mc(arrays, int(mc_seeds or objective.n_runs),
                       max_updates, shard, kernel_devices, order)
+            note("mc_rows", np.shape(objective.X)[0])
             with jax.enable_x64(True):
                 buf = jax.device_put(host, _fleet_sharding() if shard
                                      else None)

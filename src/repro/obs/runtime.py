@@ -21,7 +21,8 @@ the host event that covers most of it, and an enclosing span would
 take the name of every gap inside it.  Records are per thread, so
 several services (or a caller planning on its own thread) never mix
 their leaves.  :func:`count` adds to the open record's counters
-(jitted calls, arrays copied each way, live and unique lanes).
+(jitted calls, arrays copied each way, live and unique lanes), and
+:func:`note` sets one that is a property of the chunk.
 
 The garbage-collection hook (:func:`install_gc_hook`, reference-counted
 so each running service holds it once) counts collections and pause
@@ -88,6 +89,15 @@ def count(name: str, n: int = 1) -> None:
     rec = getattr(_TLS, "record", None)
     if rec is not None:
         rec.counts[name] = rec.counts.get(name, 0) + int(n)
+
+
+def note(name: str, n: int) -> None:
+    """Set counter ``name`` of this thread's open record to ``n``, or keep
+    the larger value noted before: a property of the record's passes
+    (such as the rows they simulated over), not a tally."""
+    rec = getattr(_TLS, "record", None)
+    if rec is not None:
+        rec.counts[name] = max(rec.counts.get(name, 0), int(n))
 
 
 class span:

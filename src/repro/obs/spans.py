@@ -66,10 +66,12 @@ LEAVES = ("serve.wait", "serve.take", "serve.pad", "planner.cache_lookup",
 #: simulated lane-slots dispatched (every run's lanes times the padded
 #: timeline), the lane-slots before each lane's deadline, the lane-slots
 #: the ``mc_ridge`` kernel stepped through (each lane to its block's
-#: longest deadline), and the passes that ran across every local device.
+#: longest deadline), the rows of the data set the passes gathered from
+#: (noted, not added: 0 where no Monte-Carlo pass ran), and the passes
+#: that ran across every local device.
 COUNTERS = ("dispatches", "h2d_arrays", "d2h_arrays", "lanes_live",
             "lanes_unique", "mc_lane_slots", "mc_live_slots",
-            "mc_run_slots", "mc_sharded_dispatches")
+            "mc_run_slots", "mc_rows", "mc_sharded_dispatches")
 
 
 def leaf_field(name: str) -> str:
@@ -120,6 +122,7 @@ class RequestSpan:
     mc_lane_slots: int = 0
     mc_live_slots: int = 0
     mc_run_slots: int = 0
+    mc_rows: int = 0
     mc_sharded_dispatches: int = 0
 
     @property

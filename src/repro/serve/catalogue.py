@@ -87,9 +87,12 @@ def make_montecarlo_objective(min_updates: int = 0, *, crn: bool = False,
                               seed_stream: str = "fold_in",
                               coarse_seeds=None, refine_rates=None,
                               coarse_strides=None, fine_radius=None,
-                              coarse_updates=None) -> MonteCarloObjective:
-    """Small deterministic ridge task (the canonical generator, scaled
-    down) for Monte-Carlo objective serving.  ``min_updates`` floors the
+                              coarse_updates=None,
+                              dataset_rows: int = 256) -> MonteCarloObjective:
+    """Deterministic ridge task (the canonical generator, 8 features) of
+    ``dataset_rows`` rows for Monte-Carlo objective serving: 256 by
+    default, the paper's 18,576 (arXiv:1906.04488 Sec. V) for a device
+    that holds the whole set.  ``min_updates`` floors the
     batched kernel's padded scan length so a service compiles ONE scan
     shape for every stream below the floor.  The keyword options expose
     the estimator/schedule knobs (common random numbers, RNG stream
@@ -99,7 +102,7 @@ def make_montecarlo_objective(min_updates: int = 0, *, crn: bool = False,
     differently-configured services never alias cache entries."""
     from repro.data.synthetic import make_regression_dataset
 
-    X, y, _ = make_regression_dataset(n=256, d=8, seed=0)
+    X, y, _ = make_regression_dataset(n=int(dataset_rows), d=8, seed=0)
     return MonteCarloObjective(X=X, y=y, n_runs=2, alpha=1e-3, seed=0,
                                min_updates=min_updates, crn=crn,
                                seed_stream=seed_stream,
@@ -139,7 +142,8 @@ def resolve_objectives(spec, mc_min_updates: int = 0,
     :func:`mc_update_floor`) and ``mc_options`` forwards estimator /
     schedule keywords to :func:`make_montecarlo_objective` (``crn``,
     ``seed_stream``, ``coarse_seeds``, ``refine_rates``,
-    ``coarse_strides``, ``fine_radius``, ``coarse_updates``).
+    ``coarse_strides``, ``fine_radius``, ``coarse_updates``,
+    ``dataset_rows``).
     """
     if spec == "all":
         names: Sequence[str] = ALL_OBJECTIVES

@@ -101,7 +101,9 @@ class ServiceConfig:
     picks the per-run RNG derivation, and ``mc_coarse_seeds`` /
     ``mc_refine_rates`` / ``mc_coarse_strides`` / ``mc_fine_radius`` /
     ``mc_coarse_updates`` install the refine-mode seed/rate/stride/
-    window/horizon schedules.  All of them flow into the objective's
+    window/horizon schedules, and ``mc_dataset_rows`` sizes the
+    simulated ridge data set (the paper's Sec. V set has 18,576 rows).
+    All of them flow into the objective's
     cache token (and the engine into the planner's cache-context
     prefix), so differently-configured services never alias entries.
     """
@@ -119,6 +121,7 @@ class ServiceConfig:
     mc_coarse_strides: Optional[Tuple[int, ...]] = None
     mc_fine_radius: Optional[int] = None
     mc_coarse_updates: Optional[int] = None
+    mc_dataset_rows: int = 256
     policy_id: str = "link_aware"
     cache_size: int = 8192
     sig_digits: int = 3
@@ -206,6 +209,9 @@ class ServiceConfig:
         if self.mc_impl not in MC_IMPLS:
             raise ValueError(
                 f"unknown mc_impl {self.mc_impl!r}; valid: {MC_IMPLS}")
+        if self.mc_dataset_rows < 1:
+            raise ValueError(
+                f"mc_dataset_rows must be >= 1, got {self.mc_dataset_rows}")
         if self.max_pending < 0:
             raise ValueError(
                 f"max_pending must be >= 0, got {self.max_pending}")
@@ -277,7 +283,8 @@ class PlanningService:
                                 refine_rates=cfg.mc_refine_rates,
                                 coarse_strides=cfg.mc_coarse_strides,
                                 fine_radius=cfg.mc_fine_radius,
-                                coarse_updates=cfg.mc_coarse_updates))
+                                coarse_updates=cfg.mc_coarse_updates,
+                                dataset_rows=cfg.mc_dataset_rows))
         self.policy = policy if policy is not None \
             else policy_spec(cfg.policy_id).cls()
         self.round_planner = RoundPlanner(grid_size=cfg.grid_size,

@@ -31,6 +31,7 @@ from repro.fleet import FleetPlanner, ScenarioBatch, objective_token
 from repro.fleet.objective_kernels import fleet_solve
 from repro.fleet.tracing import trace_delta
 from repro.kernels import mc_ridge_slab
+from repro.kernels.mc_ridge import row_table
 from repro.kernels.ref import mc_ridge_ref
 from repro.serve import LINK_FACTORIES, default_consts, resolve_objectives
 
@@ -74,8 +75,8 @@ def test_mc_ridge_slab_matches_numpy_ref(fused):
     ys = rng.normal(size=n).astype(np.float32)
     ix = rng.integers(0, n, size=(slab, L)).astype(np.int32)
     m = (rng.random(size=(slab, L)) < 0.7).astype(np.float32)
-    out = mc_ridge_slab(W, Xs, ys, ix, m, alpha=1e-3, lam=0.1,
-                        fused=fused, interpret=True)
+    out = mc_ridge_slab(W, row_table(Xs, ys), ix, m, alpha=1e-3,
+                        lam=0.1, fused=fused, interpret=True)
     ref = mc_ridge_ref(W, Xs, ys, ix, m, alpha=1e-3, lam=0.1, fused=fused)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-6)
 
@@ -92,8 +93,8 @@ def test_mc_ridge_slab_dead_lane_passthrough():
     m = np.ones((slab, L), np.float32)
     m[:, 2] = 0.0
     for fused in (False, True):
-        out = np.asarray(mc_ridge_slab(W, Xs, ys, ix, m, alpha=1e-3,
-                                       lam=0.1, fused=fused,
+        out = np.asarray(mc_ridge_slab(W, row_table(Xs, ys), ix, m,
+                                       alpha=1e-3, lam=0.1, fused=fused,
                                        interpret=True))
         np.testing.assert_array_equal(out[2], W[2])
 
@@ -123,13 +124,125 @@ def test_mc_ridge_slab_stops_each_block_at_its_last_live_slot(fused):
     assert steps[0] == 0 and 0 < steps[1] < slab and steps[2] == slab
     assert steps[1] == np.max(hi[128:256]) - j0
     kw = dict(alpha=1e-3, lam=0.1, fused=fused, interpret=True)
-    bounded = np.asarray(mc_ridge_slab(W, Xs, ys, ix, m, hi, np.int32(j0),
+    rows = row_table(Xs, ys)
+    bounded = np.asarray(mc_ridge_slab(W, rows, ix, m, hi, np.int32(j0),
                                        **kw))
-    whole = np.asarray(mc_ridge_slab(W, Xs, ys, ix, m, **kw))
+    whole = np.asarray(mc_ridge_slab(W, rows, ix, m, **kw))
     np.testing.assert_array_equal(bounded, whole)
     np.testing.assert_array_equal(bounded[:128], W[:128])
     ref = mc_ridge_ref(W, Xs, ys, ix, m, alpha=1e-3, lam=0.1, fused=fused)
     np.testing.assert_allclose(bounded, ref, rtol=2e-5, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# the row gather: one load per lane from the kernel's VMEM table of rows
+# ---------------------------------------------------------------------------
+
+
+def _gathered_rows(Xs, ys, ix):
+    """Every slot's rows of every lane as the kernel's gather reads them:
+    ``(slab, d + 1, L)``, features then the target, lanes last."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels import mc_ridge as mr
+
+    slab, L = ix.shape
+    d = Xs.shape[1]
+
+    def kernel(ix_ref, rows_ref, o_ref, g_ref):
+        for j in range(slab):
+            xr, yr = mr._load_rows(ix_ref, rows_ref, g_ref, j, d)
+            o_ref[j, :d, :] = xr
+            o_ref[j, d:, :] = yr
+
+    return np.asarray(pl.pallas_call(
+        kernel, grid=(L // mr.BLOCK_L,),
+        in_specs=[pl.BlockSpec((slab, mr.BLOCK_L), lambda i: (0, i),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((slab, d + 1, mr.BLOCK_L),
+                               lambda i: (0, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((slab, d + 1, L), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((mr.BLOCK_L, mr.ROW_LANES),
+                                   jnp.float32)],
+        interpret=True)(ix, mr.row_table(Xs, ys)))
+
+
+@pytest.mark.parametrize("n", [256, 1000])
+def test_row_gather_is_bitwise_the_rows(n):
+    """Each lane's row and target, loaded from the table by the slot's
+    index, are bitwise ``Xs[ix]`` and ``ys[ix]``: indices at both ends of
+    the set, and n = 1,000, not a multiple of 128, with 8 padding rows."""
+    rng = np.random.default_rng(n)
+    d, slab, L = 8, 3, 256
+    Xs = rng.normal(size=(n, d)).astype(np.float32)
+    ys = rng.normal(size=n).astype(np.float32)
+    ix = rng.integers(0, n, size=(slab, L)).astype(np.int32)
+    ix[0, :2] = (0, n - 1)
+    got = _gathered_rows(Xs, ys, ix)
+    np.testing.assert_array_equal(got[:, :d, :],
+                                  np.moveaxis(Xs[ix], -1, 1))
+    np.testing.assert_array_equal(got[:, d, :], ys[ix])
+
+
+def _scan_slab(W, Xs, ys, ix, m, *, alpha, lam, fused):
+    """The slab as a ``lax.scan`` over its slots with an ``Xs[ix]`` gather,
+    every lane through every slot, in the kernel's update order (the
+    einsum lane dot, as the CPU solves run it)."""
+    import jax.numpy as jnp
+    from repro.core.pipeline import ridge_dot
+    n = Xs.shape[0]
+    c_reg = np.float32(2.0 * alpha * lam / n)
+    c_2a = np.float32(-2.0 * alpha)
+    Xs, ys = jnp.asarray(Xs), jnp.asarray(ys)
+
+    def step(Wt, slot):
+        ixr, mr = slot
+        xr, yr, mr = Xs[ixr].T, ys[ixr][None, :], mr[None, :]
+        dot = ridge_dot(Wt, xr, axis=0, keepdims=True, ordered=False)
+        if fused:
+            c1, c2 = 1.0 - mr * c_reg, mr * c_2a * (dot - yr)
+            return Wt * c1 + xr * c2, None
+        g = 2.0 * (dot - yr) * xr + 2.0 * lam / n * Wt
+        return jnp.where(mr > 0.0, Wt - alpha * g, Wt), None
+
+    Wt, _ = jax.jit(lambda: jax.lax.scan(step, jnp.asarray(W).T,
+                                         (jnp.asarray(ix),
+                                          jnp.asarray(m))))()
+    return np.asarray(Wt.T)
+
+
+@pytest.mark.parametrize("n", [256, 1000])
+@pytest.mark.parametrize("fused", [False, True])
+def test_slab_kernel_is_bitwise_the_gathered_scan(n, fused):
+    """The slab kernel returns bitwise the weights of a ``lax.scan`` that
+    gathers ``Xs[ix]``, and meets the numpy oracle: per-block
+    deadlines (a block past its deadline, one cut short, one running the
+    whole slab), dead lanes masked over the whole slab, a part last
+    block."""
+    rng = np.random.default_rng(7 + n)
+    L, d, slab, j0 = 300, 8, 16, 32
+    hi = np.empty(L, np.int32)
+    hi[:128] = rng.integers(0, j0 + 1, size=128)
+    hi[128:256] = rng.integers(j0, j0 + 6, size=128)
+    hi[256:] = rng.integers(j0, j0 + 2 * slab, size=L - 256)
+    hi[270] = j0 + slab
+    W = rng.normal(size=(L, d)).astype(np.float32)
+    Xs = rng.normal(size=(n, d)).astype(np.float32)
+    ys = rng.normal(size=n).astype(np.float32)
+    ix = rng.integers(0, n, size=(slab, L)).astype(np.int32)
+    live = (j0 + np.arange(slab))[:, None] < hi[None, :]
+    m = (live & (rng.random(size=(slab, L)) < 0.8)).astype(np.float32)
+    m[:, 260:264] = 0.0                               # dead lanes
+    kw = dict(alpha=1e-3, lam=0.1, fused=fused)
+    out = np.asarray(mc_ridge_slab(W, row_table(Xs, ys), ix, m, hi,
+                                   np.int32(j0), interpret=True,
+                                   ordered=False, **kw))
+    np.testing.assert_array_equal(out, _scan_slab(W, Xs, ys, ix, m, **kw))
+    np.testing.assert_array_equal(out[260:264], W[260:264])
+    ref = mc_ridge_ref(W, Xs, ys, ix, m, alpha=1e-3, lam=0.1, fused=fused)
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -142,8 +255,19 @@ def test_mc_ridge_slab_stops_each_block_at_its_last_live_slot(fused):
 def test_pallas_engine_bitwise_matches_scan(crn):
     """The ``mc_impl="pallas"`` fleet solve returns BITWISE the scan
     engine's plans — exact-RNG and CRN forms both (the shared host-side
-    tables + one-hot MXU gather make the kernel exact, not approximate)."""
-    X, y = _ridge_data()
+    tables + the bitwise row gather make the kernel exact, not
+    approximate)."""
+    _assert_pallas_plans_are_the_scans(_ridge_data(), crn)
+
+
+@pytest.mark.parametrize("crn", [False, True])
+def test_pallas_engine_bitwise_matches_scan_over_1000_rows(crn):
+    """The same over 1,000 rows of 8 features, not a multiple of 128."""
+    _assert_pallas_plans_are_the_scans(_ridge_data(n=1000, d=8), crn)
+
+
+def _assert_pallas_plans_are_the_scans(data, crn):
+    X, y = data
     mc = MonteCarloObjective(X=X, y=y, n_runs=2, alpha=1e-3, seed=0,
                              crn=crn)
     scs = _scenarios()

@@ -61,14 +61,15 @@ def test_mc_ridge_slab_compiles(one_chip, fused):
     a 256-scenario bucket x 1 rate x 32-wide fine window of lanes.  Under
     ``enable_x64``, as the Monte-Carlo solve calls it: Mosaic lowers no
     int64, so an index that widens there fails here."""
-    from repro.kernels.mc_ridge import mc_ridge_slab
+    from repro.kernels.mc_ridge import mc_ridge_slab, row_table
     n, d, lanes, slab = 256, 8, 256 * 32, 256
     f32 = jnp.float32
     s = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt,
                                                    sharding=one_chip)
     with jax.enable_x64(True):
         compiled, _ = _compile(
-            lambda W, X, y, ix, m: mc_ridge_slab(W, X, y, ix, m, alpha=1e-3,
+            lambda W, X, y, ix, m: mc_ridge_slab(W, row_table(X, y), ix, m,
+                                                 alpha=1e-3,
                                                  lam=0.05, fused=fused),
             s((lanes, d)), s((n, d)), s((n,)), s((slab, lanes), jnp.int32),
             s((slab, lanes)))
@@ -81,7 +82,7 @@ def test_mc_ridge_slab_compiles_with_lane_deadlines(one_chip, fused):
     and the slab's first slot, reduced to one slot count per 128-lane
     block and prefetched as scalars, so each block's slot loop has a trip
     count known only on the chip."""
-    from repro.kernels.mc_ridge import mc_ridge_slab
+    from repro.kernels.mc_ridge import mc_ridge_slab, row_table
     n, d, lanes, slab = 256, 8, 256 * 32, 256
     f32, i32 = jnp.float32, jnp.int32
     s = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt,
@@ -89,7 +90,8 @@ def test_mc_ridge_slab_compiles_with_lane_deadlines(one_chip, fused):
     with jax.enable_x64(True):
         compiled, _ = _compile(
             lambda W, X, y, ix, m, hi, j0: mc_ridge_slab(
-                W, X, y, ix, m, hi, j0, alpha=1e-3, lam=0.05, fused=fused),
+                W, row_table(X, y), ix, m, hi, j0, alpha=1e-3, lam=0.05,
+                fused=fused),
             s((lanes, d)), s((n, d)), s((n,)), s((slab, lanes), i32),
             s((slab, lanes)), s((lanes,), i32), s((), i32))
     assert "tpu_custom_call" in compiled.as_text()
@@ -112,6 +114,50 @@ def test_montecarlo_solve_compiles_with_pallas(one_chip):
     with jax.enable_x64(True):
         compiled = solve.lower(_shapes(one_chip, host), layout=layout,
                                max_updates=mc_update_floor(2048),
+                               mc_impl="pallas").compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_mc_ridge_slab_compiles_at_the_papers_rows(one_chip, fused):
+    """The paper's 18,576-row data set: the kernel holds the rows as one
+    9.5-MB VMEM table, past the 16-MB default scoped VMEM once buffered
+    twice, and takes lane deadlines, as the served solve calls it."""
+    from repro.kernels.mc_ridge import mc_ridge_slab, row_table
+    n, d, lanes, slab = 18576, 8, 64 * 60, 256
+    f32, i32 = jnp.float32, jnp.int32
+    s = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt,
+                                                   sharding=one_chip)
+    with jax.enable_x64(True):
+        compiled, _ = _compile(
+            lambda W, X, y, ix, m, hi, j0: mc_ridge_slab(
+                W, row_table(X, y), ix, m, hi, j0, alpha=1e-3, lam=0.05,
+                fused=fused),
+            s((lanes, d)), s((n, d)), s((n,)), s((slab, lanes), i32),
+            s((slab, lanes)), s((lanes,), i32), s((), i32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_montecarlo_solve_compiles_at_the_papers_rows(one_chip):
+    """The served Monte-Carlo solve of ``mc-paper-open``: a 64-request
+    bucket of N = 18,576 over every link family, the 12-point grid at
+    five rates, the 131,072-slot timeline of ``n_max=18577``, over the
+    paper's 18,576-row data set."""
+    from repro.fleet.link_kernels import kernel_table_version
+    from repro.fleet.objective_kernels import _mc_args, _mc_solve_for
+    from repro.serve.catalogue import make_montecarlo_objective, \
+        mc_update_floor
+    objective = make_montecarlo_objective(mc_update_floor(18577), crn=True,
+                                          dataset_rows=18576)
+    batch = ScenarioBatch.from_scenarios(
+        synth_requests(64, seed=1, models=ALL_MODELS, n_max=18577))
+    arrays = FleetPlanner._solve_arrays(batch, fleet_grid(batch.N, 12))
+    solve, _ = _mc_solve_for(objective, kernel_table_version(), False)
+    host, layout = _mc_args(arrays)
+    assert mc_update_floor(18577) == 131072
+    with jax.enable_x64(True):
+        compiled = solve.lower(_shapes(one_chip, host), layout=layout,
+                               max_updates=mc_update_floor(18577),
                                mc_impl="pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()
 
